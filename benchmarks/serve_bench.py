@@ -35,16 +35,12 @@ Run:  PYTHONPATH=src python benchmarks/serve_bench.py [--quick]
 from __future__ import annotations
 
 import argparse
-import tempfile
-import threading
-import time
 from typing import Dict, List
 
-from repro.core import SimCluster, SimCostModel, SyndeoCluster
-from repro.core.rendezvous import FileRendezvous
-from repro.core.worker import HeadServer, _dec, _enc, _request, run_worker
+from repro.core import SimCluster, SimCostModel
 from repro.serve.engine import Request, StubEngine
-from repro.serve.router import ActorReplicaHandle, ReplicaActor, Router
+from repro.serve.fleet import serve_fleet
+from repro.serve.router import Router
 
 MB = 1_000_000
 
@@ -165,70 +161,17 @@ def print_weights(wr: Dict[str, float]):
 def actor_run(n_requests: int = 3, tokens: int = 4) -> Dict[str, float]:
     """Real sockets: one worker-hosted ReplicaActor behind the router,
     with the router's stats_sink feeding the head's serve gauges."""
-    with tempfile.TemporaryDirectory() as tmp:
-        cluster = SyndeoCluster(rendezvous=FileRendezvous(tmp))
-        server = HeadServer(cluster)
-        server.attach()
-        t = threading.Thread(
-            target=run_worker, args=(tmp, cluster.cluster_id, "bench-w0"),
-            kwargs={"max_idle_s": 1.0,
-                    "actor_factories": {"replica": ReplicaActor}},
-            daemon=True)
-        t.start()
-        try:
-            deadline = time.time() + 20
-            while time.time() < deadline and not any(
-                    w.alive for w in cluster.scheduler.workers.values()):
-                time.sleep(0.05)
-            host, port, token = "127.0.0.1", server.port, cluster.token
-            made = _request(host, port, token,
-                            {"op": "actor_create", "factory": "replica",
-                             "actor": "rep0",
-                             "kwargs": {"batch_slots": 2}})
-            assert made["ok"], made
-            cap = made["cap"]
-
-            def call(payload, timeout=10.0):
-                sent = _request(host, port, token,
-                                {"op": "actor_call", "actor": "rep0",
-                                 "cap": cap, "payload": _enc(payload)})
-                assert sent["ok"], sent
-                limit = time.time() + timeout
-                while time.time() < limit:
-                    got = _request(host, port, token,
-                                   {"op": "actor_result",
-                                    "call": sent["call"]})
-                    if got.get("done"):
-                        assert not got.get("error"), got
-                        return _dec(got["value"])
-                    time.sleep(0.05)
-                raise AssertionError("actor call never completed")
-
-            router = Router(stats_sink=server.serve_stats.update)
-            router.add_replica("rep0", ActorReplicaHandle(call))
-            reqs = [Request(id=i, prompt=[i, 17], max_new_tokens=tokens)
-                    for i in range(n_requests)]
-            for q in reqs:
-                assert router.submit(q)
-            done = router.flush(max_ticks=200)
-            outputs_ok = (
-                sorted(q.id for q in done) == sorted(q.id for q in reqs)
-                and all(q.output == StubEngine.stub_output(
-                    q.prompt, q.max_new_tokens) for q in reqs))
-            gauges = server.dispatch({"op": "metrics"})
-            bye = _request(host, port, token,
-                           {"op": "actor_exit", "actor": "rep0",
-                            "cap": cap})
-            assert bye["ok"], bye
-            deadline = time.time() + 20
-            while time.time() < deadline and (
-                    "rep0" in cluster.scheduler.actors
-                    or "bench-w0" in cluster.scheduler.workers):
-                time.sleep(0.1)
-            t.join(timeout=10)
-        finally:
-            server.shutdown()
-            cluster.shutdown()
+    with serve_fleet([StubEngine(2)], call_timeout_s=10.0) as fleet:
+        reqs = [Request(id=i, prompt=[i, 17], max_new_tokens=tokens)
+                for i in range(n_requests)]
+        for q in reqs:
+            assert fleet.router.submit(q)
+        done = fleet.router.flush(max_ticks=200)
+        outputs_ok = (
+            sorted(q.id for q in done) == sorted(q.id for q in reqs)
+            and all(q.output == StubEngine.stub_output(
+                q.prompt, q.max_new_tokens) for q in reqs))
+        gauges = fleet.server.dispatch({"op": "metrics"})
     return {"completed": float(len(done)),
             "outputs_ok": float(outputs_ok),
             "gauge_requests": float(gauges.get("syndeo_serve_requests", -1)),

@@ -10,13 +10,11 @@
     reason = "relay path: head-local store, bounded control ops"
 
 ``reason`` is mandatory: a suppression without a written justification
-is a bug, not a baseline.  Parsed with :mod:`tomllib` when available
-(Python >= 3.11); otherwise a minimal TOML-subset parser keeps the gate
-usable on 3.10 without new dependencies.
+is a bug, not a baseline.  Parsed with :mod:`tomllib`.
 """
 from __future__ import annotations
 
-import json
+import tomllib
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
@@ -27,13 +25,7 @@ _OPTIONAL = ("function", "match")
 
 
 def load_baseline(path: str) -> List[Dict[str, str]]:
-    text = Path(path).read_text()
-    try:
-        import tomllib
-    except ModuleNotFoundError:
-        data = _parse_toml_subset(text)
-    else:
-        data = tomllib.loads(text)
+    data = tomllib.loads(Path(path).read_text())
     entries = data.get("suppress", [])
     if not isinstance(entries, list):
         raise ValueError("baseline: [[suppress]] must be an array of "
@@ -85,51 +77,3 @@ def _match(f: Finding,
         return i
     return None
 
-
-def _parse_toml_subset(text: str) -> Dict[str, object]:
-    """Array-of-tables + scalar key/value lines; enough for a baseline
-    file authored by this repo."""
-    data: Dict[str, object] = {}
-    current: Optional[Dict[str, object]] = None
-    for lineno, raw in enumerate(text.splitlines(), 1):
-        line = raw.strip()
-        if not line or line.startswith("#"):
-            continue
-        if line.startswith("[[") and line.endswith("]]"):
-            name = line[2:-2].strip()
-            current = {}
-            data.setdefault(name, [])
-            arr = data[name]
-            if not isinstance(arr, list):
-                raise ValueError(f"baseline line {lineno}: {name!r} "
-                                 "is both table and array")
-            arr.append(current)
-            continue
-        if line.startswith("[") and line.endswith("]"):
-            current = {}
-            data[line[1:-1].strip()] = current
-            continue
-        if "=" in line:
-            key, _, value = line.partition("=")
-            target = current if current is not None else data
-            target[key.strip()] = _parse_scalar(value.strip(), lineno)
-            continue
-        raise ValueError(f"baseline line {lineno}: unsupported syntax "
-                         f"{raw!r}")
-    return data
-
-
-def _parse_scalar(v: str, lineno: int) -> object:
-    if v.startswith('"') and v.endswith('"'):
-        return json.loads(v)  # handles \" escapes
-    if v in ("true", "false"):
-        return v == "true"
-    try:
-        return int(v)
-    except ValueError:
-        pass
-    try:
-        return float(v)
-    except ValueError:
-        raise ValueError(
-            f"baseline line {lineno}: unsupported value {v!r}") from None

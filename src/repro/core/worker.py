@@ -578,6 +578,7 @@ class HeadServer:
         self._actor_outbox: Dict[str, List[Dict[str, Any]]] = {}
         self._actor_results: Dict[str, Dict[str, Any]] = {}
         self._actor_exits_asked: set = set()
+        self._actor_create_errors: Dict[str, str] = {}
         self.serve_stats: Dict[str, float] = {}
         # observability hub: shares the scheduler's registry (sojourn
         # histograms land there) and folds worker-pushed histogram
@@ -858,10 +859,14 @@ class HeadServer:
         """Worker-side exit ack: the replica finished its in-flight work
         and unhosted -- only now does the scheduler release the actor's
         lifetime resource hold (and a drain of the node can complete).
-        Caller holds the cluster lock (top level or batch frame)."""
+        An ack carrying `error` reports a create that failed; its text
+        answers later calls to that actor. Caller holds the cluster lock
+        (top level or batch frame)."""
         aid = str(msg["actor"])
         released = self.cluster.scheduler.remove_actor(aid)
         self._actor_exits_asked.discard(aid)
+        if msg.get("error"):
+            self._actor_create_errors[aid] = str(msg["error"])
         return {"ok": True, "released": released}
 
     def dispatch(self, msg: Dict[str, Any]) -> Dict[str, Any]:
@@ -1100,8 +1105,12 @@ class HeadServer:
             aid = str(msg["actor"])
             with c._lock:
                 info = c.scheduler.actors.get(aid)
+                why = self._actor_create_errors.get(aid)
             if info is None:
-                return {"ok": False, "error": f"unknown actor {aid!r}"}
+                err = f"unknown actor {aid!r}"
+                if why:
+                    err = f"actor {aid!r} failed to start: {why}"
+                return {"ok": False, "error": err}
             cd = msg.get("cap") or {}
             cap = Capability(str(cd.get("object_id", "")),
                              str(cd.get("right", "")),
@@ -1860,13 +1869,15 @@ def run_worker(rendezvous_dir: str, cluster_id: str, worker_id: str = "",
             return
 
     actors: Dict[str, Any] = {}    # hosted service actors (id -> instance)
+    create_errors: Dict[str, str] = {}   # actor id -> why its create failed
 
     def handle_actor_op(d: Dict[str, Any]):
         """Execute one head-queued actor lifecycle directive. Every
         outcome is acked through `pending_ops` (the next poll's batch
         frame): a create that cannot be satisfied acks an immediate
-        exit so the head releases the lifetime resource hold instead of
-        leaking it against a phantom replica."""
+        exit, carrying the factory's exception text, so the head
+        releases the lifetime resource hold instead of leaking it
+        against a phantom replica, and callers learn why."""
         aop = d.get("op")
         aid = str(d.get("actor"))
         if aop == "actor_create":
@@ -1875,21 +1886,25 @@ def run_worker(rendezvous_dir: str, cluster_id: str, worker_id: str = "",
                 if factory is None:
                     raise KeyError(f"no actor factory {d.get('factory')!r}")
                 actors[aid] = factory(**(d.get("kwargs") or {}))
-            except Exception:  # noqa: BLE001 -- unknown factory / bad
-                # kwargs: unhost immediately, the head-side registration
-                # must not outlive the failed instantiation
+            except Exception as e:  # noqa: BLE001 -- unknown factory, bad
+                # kwargs, a replica that cannot load: unhost immediately,
+                # the head-side registration must not outlive the failure
+                create_errors[aid] = f"{type(e).__name__}: {e}"
                 pending_ops.append((
-                    {"op": "actor_exit", "worker": wid, "actor": aid},
-                    None))
+                    {"op": "actor_exit", "worker": wid, "actor": aid,
+                     "error": create_errors[aid]}, None))
             return
         if aop == "actor_call":
             call_id = str(d.get("call"))
             inst = actors.get(aid)
             if inst is None:
+                why = create_errors.get(aid)
+                err = f"actor {aid!r} is not hosted here"
+                if why:
+                    err += f": create failed: {why}"
                 pending_ops.append((
                     {"op": "actor_result", "worker": wid, "actor": aid,
-                     "call": call_id,
-                     "error": f"actor {aid!r} is not hosted here"}, None))
+                     "call": call_id, "error": err}, None))
                 return
             try:
                 payload = (_dec(d["payload"])

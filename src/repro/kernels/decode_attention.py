@@ -6,7 +6,8 @@ online-softmax state in VMEM scratch, and applies the per-sequence validity
 bound so continuous batching can mix sequences of different lengths.
 
 Layout: q (B, Hq, D); k/v (B, Hkv, S, D) [bf16 or int8 + (B, Hkv, S, 1)
-fp32 scales]; valid_len (B, 1) int32. Out (B, Hq, D).
+fp32 scales]; valid_len (B,) int32, scalar-prefetched into SMEM so the
+kernel can branch on it. Out (B, Hq, D).
 """
 from __future__ import annotations
 
@@ -22,9 +23,10 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, vl_ref, o_ref,
+def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                    acc_ref, m_ref, l_ref, *, scale: float, block_k: int,
                    n_kb: int, int8: bool):
+    b = pl.program_id(0)
     jk = pl.program_id(2)
 
     @pl.when(jk == 0)
@@ -33,7 +35,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, vl_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    valid = vl_ref[0, 0]
+    valid = vl_ref[b]
     k_start = jk * block_k
 
     @pl.when(k_start < valid)
@@ -83,29 +85,34 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         k_scale = jnp.ones((B, Hkv, S, 1), jnp.float32)
     if v_scale is None:
         v_scale = jnp.ones((B, Hkv, S, 1), jnp.float32)
-    vl = valid_len.reshape(B, 1).astype(jnp.int32)
+    vl = valid_len.reshape(B).astype(jnp.int32)
 
     kernel = functools.partial(_decode_kernel, scale=1.0 / math.sqrt(D),
                                block_k=block_k, n_kb=n_kb, int8=int8)
     q3 = q.reshape(B, Hq, 1, D)
-    out = pl.pallas_call(
-        kernel,
+    # index maps take the scalar-prefetch ref after the grid indices
+    kv_block = lambda b, h, j, vl: (b, h // R, j, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hq, n_kb),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h // R, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h // R, j, 0)),
-            pl.BlockSpec((1, 1, block_k, 1), lambda b, h, j: (b, h // R, j, 0)),
-            pl.BlockSpec((1, 1, block_k, 1), lambda b, h, j: (b, h // R, j, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, j: (b, 0)),
+            pl.BlockSpec((1, 1, 1, D), lambda b, h, j, vl: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, D), kv_block),
+            pl.BlockSpec((1, 1, block_k, D), kv_block),
+            pl.BlockSpec((1, 1, block_k, 1), kv_block),
+            pl.BlockSpec((1, 1, block_k, 1), kv_block),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h, j, vl: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, D), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, D), q.dtype),
         interpret=interpret,
-    )(q3, k, v, k_scale, v_scale, vl)
+    )(vl, q3, k, v, k_scale, v_scale)
     return out.reshape(B, Hq, D)

@@ -3,8 +3,8 @@
 Attention here is the *reference* (pure-jnp) path: a blocked online-softmax
 ("flash") implementation whose lowered memory is linear in sequence length,
 so the 512-device dry-run's memory_analysis reflects a production-quality
-attention. On real TPUs the Pallas kernels in repro.kernels replace the
-inner block computation (see kernels/ops.py: use_pallas flag).
+attention. The Pallas kernels in repro.kernels (kernels/ops.py) are
+separate implementations of the same operations; no model calls them yet.
 """
 from __future__ import annotations
 

@@ -99,7 +99,7 @@ def compressed_psum_mean(g: jax.Array, err: jax.Array, axis_name: str
 
 def make_compressed_grad_reduce(mesh, axis_name: str):
     """shard_map wrapper: reduce a replicated-per-DP-shard gradient pytree."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def reduce_tree(grads, errs):

@@ -9,7 +9,9 @@ compiled decode step, no re-compilation as requests come and go):
 
 On a pod this engine is one long-lived Syndeo actor per model replica; the
 Syndeo scheduler routes request batches to replicas (placement groups pin
-them to pod slices).
+them to pod slices). Each engine is pinned to one device: its params,
+cache and step inputs are committed there, so one process can host one
+replica per chip.
 """
 from __future__ import annotations
 
@@ -35,16 +37,21 @@ class Request:
 
 
 class ServeEngine:
-    def __init__(self, model: Model, params, batch_slots: int, max_len: int):
+    def __init__(self, model: Model, params, batch_slots: int, max_len: int,
+                 device: Optional[jax.Device] = None):
+        """`device` (default: the first device) holds the replica; params
+        living elsewhere are copied to it."""
         self.model = model
-        self.params = params
+        self.device = device or jax.devices()[0]
+        self.params = self._put(params)
         self.B = batch_slots
         self.max_len = max_len
-        self.cache = model.init_cache(batch_slots, max_len)
-        self.positions = jnp.zeros((batch_slots,), jnp.int32)
+        with jax.default_device(self.device):   # built in place, no hop
+            self.cache = self._put(model.init_cache(batch_slots, max_len))
+            self.positions = self._put(jnp.zeros((batch_slots,), jnp.int32))
+            self.tokens = self._put(jnp.zeros((batch_slots, 1), jnp.int32))
         self.slot_req: List[Optional[Request]] = [None] * batch_slots
         self.queue: "collections.deque[Request]" = collections.deque()
-        self.tokens = jnp.zeros((batch_slots, 1), jnp.int32)
         self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
         self._prefill_one = jax.jit(self._prefill_impl)
         self._completed: List[Request] = []
@@ -53,6 +60,10 @@ class ServeEngine:
 
     def _prefill_impl(self, params, tokens):
         return self.model.prefill(params, {"tokens": tokens})
+
+    def _put(self, x):
+        """Commit a host value (or pytree) to this engine's device."""
+        return jax.device_put(x, self.device)
 
     # -- request management ------------------------------------------------------
 
@@ -85,7 +96,7 @@ class ServeEngine:
             if self.slot_req[slot] is not None or not self.queue:
                 continue
             req = self.queue.popleft()
-            prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            prompt = self._put(np.asarray(req.prompt, np.int32)[None, :])
             logits, pcache = self._prefill_one(self.params, prompt)
             next_tok = int(jnp.argmax(logits[0, -1]))
             req.output.append(next_tok)
@@ -130,7 +141,7 @@ class ServeEngine:
                 self.slot_req[s] = None
                 self._completed.append(req)
                 self.stats["completed"] += 1
-        self.tokens = jnp.asarray(next_tokens, jnp.int32)[:, None]
+        self.tokens = self._put(next_tokens.astype(np.int32)[:, None])
         return len(active)
 
     def pop_completed(self) -> List[Request]:

@@ -9,8 +9,7 @@ import pytest
 
 from repro.analysis import run_analysis
 from repro.analysis.__main__ import main as lint_main
-from repro.analysis.baseline import (_parse_toml_subset, apply_baseline,
-                                     load_baseline)
+from repro.analysis.baseline import apply_baseline, load_baseline
 from repro.analysis.model import Finding
 
 REPO = Path(__file__).resolve().parents[1]
@@ -113,8 +112,9 @@ def test_baseline_loader_rejects_missing_reason(tmp_path):
         load_baseline(str(p))
 
 
-def test_toml_subset_parser_round_trips_the_shape():
-    data = _parse_toml_subset(textwrap.dedent('''
+def test_baseline_loader_round_trips_the_shape(tmp_path):
+    p = tmp_path / "b.toml"
+    p.write_text(textwrap.dedent('''
         # comment
         [[suppress]]
         rule = "SYN-A002"
@@ -126,16 +126,14 @@ def test_toml_subset_parser_round_trips_the_shape():
         file = "cluster.py"
         reason = "bounded"
     '''))
-    assert [e["rule"] for e in data["suppress"]] == ["SYN-A002",
-                                                     "SYN-L001"]
-    assert '"blob"' in data["suppress"][0]["reason"]
+    entries = load_baseline(str(p))
+    assert [e["rule"] for e in entries] == ["SYN-A002", "SYN-L001"]
+    assert '"blob"' in entries[0]["reason"]
 
 
-def test_repo_baseline_parses_with_fallback_parser():
-    # CI (3.11) parses with tomllib; this keeps the 3.10 fallback honest
-    text = (REPO / "analysis" / "baseline.toml").read_text()
-    data = _parse_toml_subset(text)
-    assert all(e.get("reason") for e in data["suppress"])
+def test_repo_baseline_loads():
+    entries = load_baseline(str(REPO / "analysis" / "baseline.toml"))
+    assert entries and all(e.get("reason") for e in entries)
 
 
 # -- CLI ----------------------------------------------------------------

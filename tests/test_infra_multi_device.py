@@ -35,9 +35,10 @@ def test_small_mesh_dryrun_train_and_decode():
         from repro.optim.optimizers import make_optimizer, warmup_cosine
         from repro.train.steps import make_train_step, make_init_state
         from repro.sharding import axes as AX
+        from repro.launch.mesh import make_mesh
         from repro.roofline import HloCostModel, roofline_terms
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = {"batch": ("data",), "model": ("model",), "expert": ("data",),
                  "ep_batch": (), "fsdp": (), "seq": ()}
         cfg = get_config("llama3-8b", smoke=True)
@@ -93,7 +94,7 @@ def test_compressed_allreduce_matches_psum():
     out = _run("""
         import jax, jax.numpy as jnp
         from functools import partial
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import compressed_psum_mean
 

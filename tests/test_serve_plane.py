@@ -24,6 +24,7 @@ notices drain with zero hot-producer re-execution.
 """
 import threading
 import time
+from functools import partial
 
 import pytest
 
@@ -37,8 +38,9 @@ from repro.core import SimCluster, SimCostModel, SyndeoCluster
 from repro.core.autoscaler import (AutoscalerConfig, ReplicaAutoscaler,
                                    ReplicaScalingConfig)
 from repro.core.rendezvous import FileRendezvous
-from repro.core.worker import HeadServer, _dec, _enc, _request, run_worker
+from repro.core.worker import HeadServer, _request, run_worker
 from repro.serve.engine import Request, StubEngine
+from repro.serve.fleet import actor_caller
 from repro.serve.router import ActorReplicaHandle, ReplicaActor, Router
 
 
@@ -434,30 +436,12 @@ def test_socket_actor_keeps_worker_alive_past_idle_timeout(tmp_path):
         while time.time() < deadline and not any(
                 w.alive for w in cluster.scheduler.workers.values()):
             time.sleep(0.05)
-        host, port, token = "127.0.0.1", server.port, cluster.token
-
-        made = _request(host, port, token,
-                        {"op": "actor_create", "factory": "replica",
-                         "actor": "rep0", "kwargs": {"batch_slots": 2}})
+        rpc = partial(_request, "127.0.0.1", server.port, cluster.token)
+        made = rpc({"op": "actor_create", "factory": "replica",
+                    "actor": "rep0", "kwargs": {"batch_slots": 2}})
         assert made["ok"] and made["worker"] == "sv-w0"
         cap = made["cap"]
-
-        def call(payload, timeout=10.0):
-            sent = _request(host, port, token,
-                            {"op": "actor_call", "actor": "rep0",
-                             "cap": cap, "payload": _enc(payload)})
-            assert sent["ok"]
-            limit = time.time() + timeout
-            while time.time() < limit:
-                got = _request(host, port, token,
-                               {"op": "actor_result", "call": sent["call"]})
-                if got.get("done"):
-                    assert "error" not in got or not got["error"], got
-                    return _dec(got["value"])
-                time.sleep(0.05)
-            raise AssertionError("actor call never completed")
-
-        handle = ActorReplicaHandle(call)
+        handle = ActorReplicaHandle(actor_caller(rpc, "rep0", cap, 10.0))
         router = Router()
         router.add_replica("rep0", handle)
         reqs = _reqs(3, tokens=4)
@@ -475,8 +459,7 @@ def test_socket_actor_keeps_worker_alive_past_idle_timeout(tmp_path):
         assert w is not None and w.alive and "rep0" in w.actors
 
         # graceful exit releases the hold; NOW the idle clock runs again
-        bye = _request(host, port, token,
-                       {"op": "actor_exit", "actor": "rep0", "cap": cap})
+        bye = rpc({"op": "actor_exit", "actor": "rep0", "cap": cap})
         assert bye["ok"]
         deadline = time.time() + 20
         while time.time() < deadline and (
@@ -485,6 +468,51 @@ def test_socket_actor_keeps_worker_alive_past_idle_timeout(tmp_path):
             time.sleep(0.1)
         assert "rep0" not in cluster.scheduler.actors
         assert "sv-w0" not in cluster.scheduler.workers
+        t.join(timeout=10)
+        assert not t.is_alive()
+    finally:
+        server.shutdown()
+        cluster.shutdown()
+
+
+def test_socket_actor_create_failure_reaches_the_caller(tmp_path):
+    """A replica factory that raises (a model that does not fit, a compile
+    error) must not look like a replica that quietly vanished: the
+    exception text reaches the caller, and the head releases the hold."""
+    def broken(**kwargs):
+        raise MemoryError("RESOURCE_EXHAUSTED: replica does not fit")
+
+    cluster = SyndeoCluster(rendezvous=FileRendezvous(str(tmp_path)))
+    server = HeadServer(cluster)
+    server.attach()
+    t = threading.Thread(
+        target=run_worker, args=(str(tmp_path), cluster.cluster_id, "sv-w1"),
+        kwargs={"max_idle_s": 1.0, "actor_factories": {"replica": broken}},
+        daemon=True)
+    t.start()
+    try:
+        deadline = time.time() + 20
+        while time.time() < deadline and not any(
+                w.alive for w in cluster.scheduler.workers.values()):
+            time.sleep(0.05)
+        rpc = partial(_request, "127.0.0.1", server.port, cluster.token)
+        made = rpc({"op": "actor_create", "factory": "replica",
+                    "actor": "rep0"})
+        assert made["ok"]
+        call = actor_caller(rpc, "rep0", made["cap"], timeout_s=10.0)
+        with pytest.raises(RuntimeError,
+                           match="MemoryError: RESOURCE_EXHAUSTED"):
+            call({"kind": "stats"})
+        deadline = time.time() + 20
+        while time.time() < deadline and "rep0" in cluster.scheduler.actors:
+            time.sleep(0.05)
+        assert "rep0" not in cluster.scheduler.actors   # hold released
+        # after the exit ack the head itself still names the cause
+        with pytest.raises(RuntimeError, match="failed to start: MemoryError"):
+            call({"kind": "stats"})
+        deadline = time.time() + 20
+        while time.time() < deadline and "sv-w1" in cluster.scheduler.workers:
+            time.sleep(0.1)
         t.join(timeout=10)
         assert not t.is_alive()
     finally:
