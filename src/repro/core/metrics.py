@@ -13,6 +13,10 @@ The observability plane (ROADMAP item 3) in one module:
     scheduler owns one; the head's `MetricsHub` shares it so sojourn
     histograms, worker-folded histograms and router gauges land in one
     place.
+  * `SpanRing` / `SPANS` -- the fourth instrument: timed spans, one per
+    call or request, in a process-wide fixed-size ring (the served
+    path's flight recorder), on `time.perf_counter()` and, once JAX is
+    loaded, on the profiler trace's host plane as well.
   * `TimeSeries` / `MetricsHub` -- head-side ring-buffer history keyed
     by (metric, label): every `metrics` op snapshot is recorded, so
     dashboards get history without a second collection path.
@@ -36,9 +40,13 @@ above it.
 from __future__ import annotations
 
 import bisect
+import itertools
 import math
+import sys
 import threading
-from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+from time import perf_counter
+from typing import (Any, Callable, Dict, Iterable, List, NamedTuple, Optional,
+                    Tuple)
 
 
 def log_buckets(lo: float, hi: float, factor: float = 2.0) -> Tuple[float, ...]:
@@ -242,6 +250,150 @@ class MetricsRegistry:
                     for key, inst in sorted(fam.items())]
         for name, key, inst in flat:
             yield name, dict(key), inst
+
+
+# -- spans: the flight recorder ------------------------------------------------
+
+class Span(NamedTuple):
+    """One finished interval. `start`/`end` are `time.perf_counter()`
+    seconds; `thread` is `threading.get_ident()` of the recording thread;
+    `parent` is the id of the span open on that thread when this one
+    began (None at the top); `attrs` holds its identifiers (`call` for an
+    actor call, `req` for a request) and small integer counts."""
+    id: int
+    name: str
+    start: float
+    end: float
+    thread: int
+    parent: Optional[int]
+    attrs: Dict[str, Any]
+
+
+class _Stack(threading.local):
+    def __init__(self):
+        self.open: List["_OpenSpan"] = []
+
+
+class _OpenSpan:
+    """A span being timed by `SpanRing.span`; its `attrs` may be written
+    until it closes. With no ring (recording off) it does nothing."""
+
+    __slots__ = ("_ring", "name", "attrs", "id", "parent", "start", "_stack",
+                 "_note")
+
+    def __init__(self, ring: Optional["SpanRing"], name: str,
+                 attrs: Dict[str, Any]):
+        self._ring = ring
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self) -> "_OpenSpan":
+        ring = self._ring
+        if ring is None:
+            return self
+        self._stack = stack = ring._stack.open
+        self.parent = stack[-1].id if stack else None
+        self.id = next(ring._ids)
+        stack.append(self)
+        note = ring._note_cls or ring._annotation()
+        if note is not None and note.is_enabled():   # a profiler is tracing
+            self._note = note(self.name)
+            self._note.__enter__()
+        else:
+            self._note = None
+        self.start = perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        ring = self._ring
+        if ring is None:
+            return False
+        end = perf_counter()
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+        self._stack.pop()
+        ring._put((self.id, self.name, self.start, end,
+                   threading.get_ident(), self.parent, self.attrs))
+        return False
+
+
+class SpanRing:
+    """Spans of the served path in a fixed-size ring, newest kept.
+
+    Two ways in: `span(name, **attrs)`, a context manager timing the
+    block it wraps on the calling thread, and `record(name, start, end,
+    **attrs)`, an interval already measured whose two ends fall in
+    different threads or handlers (a queue wait, a result held for its
+    caller). A span's parent is the innermost span open on the recording
+    thread. `add(key, n)` adds to the count `key` of every open span on
+    this thread that declared it, so a count is taken where the work
+    happens.
+
+    Once `jax` is imported, every `span()` opened while a profiler
+    traces is also a `jax.profiler.TraceAnnotation` of its name, so it
+    lands on the host plane of the trace beside the device's events.
+    `record()` intervals stay in memory only. This module never imports
+    JAX itself.
+
+    Writers take no lock: ids and slots come from `itertools.count`,
+    whose `next` is atomic under the interpreter lock, and a slot is
+    written by one store. `horizon` is the end of the latest span the
+    ring has overwritten (-inf until it wraps): every span that ended
+    after it is still held, so a reader of the spans that start at or
+    after `t` has them all while `horizon < t`. `enabled` is on by
+    default (a flight recorder); turning it off makes `span`, `record`
+    and `add` do nothing."""
+
+    # a whole run of the chat cell (set-up, a 51 s window, the drain)
+    # records about 3,800 spans
+    capacity = 65536
+
+    def __init__(self):
+        self.enabled = True
+        self.horizon = -math.inf
+        # Span fields as plain tuples: a NamedTuple costs ~5x to build
+        self._buf: List[Optional[tuple]] = [None] * self.capacity
+        self._slots = itertools.count()
+        self._ids = itertools.count(1)
+        self._stack = _Stack()
+        self._note_cls: Optional[Any] = None
+
+    def _annotation(self) -> Optional[Any]:
+        """`jax.profiler.TraceAnnotation` once JAX has loaded it."""
+        profiler = sys.modules.get("jax.profiler")
+        self._note_cls = getattr(profiler, "TraceAnnotation", None)
+        return self._note_cls
+
+    def _put(self, fields: tuple) -> None:
+        i = next(self._slots) % self.capacity
+        old = self._buf[i]
+        self._buf[i] = fields
+        if old is not None and old[3] > self.horizon:
+            self.horizon = old[3]
+
+    def span(self, name: str, **attrs) -> _OpenSpan:
+        return _OpenSpan(self if self.enabled else None, name, attrs)
+
+    def record(self, name: str, start: float, end: float, **attrs) -> None:
+        if not self.enabled:
+            return
+        stack = self._stack.open
+        self._put((next(self._ids), name, start, end, threading.get_ident(),
+                   stack[-1].id if stack else None, attrs))
+
+    def add(self, key: str, n: int = 1) -> None:
+        for sp in self._stack.open:
+            if key in sp.attrs:
+                sp.attrs[key] += n
+
+    def spans(self) -> List[Span]:
+        """Every span held, by start time."""
+        return sorted((Span._make(f) for f in list(self._buf)
+                       if f is not None), key=lambda s: s.start)
+
+
+# the process-wide recorder the served path writes to
+SPANS = SpanRing()
 
 
 class TimeSeries:

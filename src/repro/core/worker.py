@@ -172,8 +172,9 @@ import uuid
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from repro.core.cluster import SyndeoCluster
-from repro.core.metrics import (Histogram, MetricsHub, build_cluster_metrics,
-                                render_dashboards, render_prometheus)
+from repro.core.metrics import (SPANS, Histogram, MetricsHub,
+                                build_cluster_metrics, render_dashboards,
+                                render_prometheus)
 from repro.core.object_store import (NodeStore, ObjectRef, RemoteNodeStore,
                                      TCPTransport, recv_frame, send_frame)
 from repro.core.rendezvous import Endpoint, FileRendezvous
@@ -579,6 +580,10 @@ class HeadServer:
         self._actor_results: Dict[str, Dict[str, Any]] = {}
         self._actor_exits_asked: set = set()
         self._actor_create_errors: Dict[str, str] = {}
+        # call id -> perf_counter when its current wait began: queued in
+        # the actor outbox (`head.outbox` span), then its result held for
+        # the client (`head.held` span)
+        self._call_wait: Dict[str, float] = {}
         self.serve_stats: Dict[str, float] = {}
         # observability hub: shares the scheduler's registry (sojourn
         # histograms land there) and folds worker-pushed histogram
@@ -850,6 +855,7 @@ class HeadServer:
     def _handle_actor_result(self, msg: Dict[str, Any]) -> Dict[str, Any]:
         """Worker-side completion report for one actor call (pure dict
         work: batch frames run it under the one cluster-lock pass)."""
+        self._call_wait[str(msg["call"])] = time.perf_counter()
         self._actor_results[str(msg["call"])] = {
             "actor": msg.get("actor"), "host": msg.get("worker"),
             "value": msg.get("value"), "error": msg.get("error")}
@@ -939,6 +945,12 @@ class HeadServer:
                             self._actor_outbox.setdefault(wid, []).append(
                                 {"op": "actor_exit", "actor": aid})
                 acts = self._actor_outbox.pop(wid, [])
+                handed = [(a["call"], self._call_wait.pop(a["call"], None))
+                          for a in acts if a.get("op") == "actor_call"]
+            now = time.perf_counter()
+            for call_id, queued in handed:
+                if queued is not None:
+                    SPANS.record("head.outbox", queued, now, call=call_id)
 
             def with_moves(reply: Dict[str, Any]) -> Dict[str, Any]:
                 if moves:
@@ -1122,6 +1134,7 @@ class HeadServer:
                 return {"ok": False, "error": str(e)}
             call_id = str(msg.get("call") or f"call-{uuid.uuid4().hex[:8]}")
             with c._lock:
+                self._call_wait[call_id] = time.perf_counter()
                 self._actor_outbox.setdefault(info.worker_id, []).append(
                     {"op": "actor_call", "actor": aid, "call": call_id,
                      "payload": msg.get("payload")})
@@ -1130,9 +1143,14 @@ class HeadServer:
             if msg.get("worker"):      # worker-side completion report
                 with c._lock:
                     return self._handle_actor_result(msg)
-            res = self._actor_results.pop(str(msg["call"]), None)
+            call_id = str(msg["call"])
+            res = self._actor_results.pop(call_id, None)
             if res is None:
                 return {"ok": True, "done": False}
+            held = self._call_wait.pop(call_id, None)
+            if held is not None:
+                SPANS.record("head.held", held, time.perf_counter(),
+                             call=call_id)
             return dict({"ok": True, "done": True}, **res)
         if op == "actor_exit":
             aid = str(msg["actor"])
@@ -1909,7 +1927,10 @@ def run_worker(rendezvous_dir: str, cluster_id: str, worker_id: str = "",
             try:
                 payload = (_dec(d["payload"])
                            if d.get("payload") is not None else None)
-                value = inst.handle(payload)
+                kind = (payload.get("kind") if isinstance(payload, dict)
+                        else None)
+                with SPANS.span("actor.handle", call=call_id, kind=kind):
+                    value = inst.handle(payload)
                 pending_ops.append((
                     {"op": "actor_result", "worker": wid, "actor": aid,
                      "call": call_id, "value": _enc(value)}, None))

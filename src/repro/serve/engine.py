@@ -16,6 +16,8 @@ replica per chip.
 from __future__ import annotations
 
 import collections
+import functools
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
@@ -23,7 +25,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.metrics import SPANS
 from repro.models.registry import Model
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+@functools.cache
+def _record_compiles() -> None:
+    """Once per process: record each backend compile as an
+    `engine.compile` span, [now - its duration, now], under the span
+    open on the compiling thread (a compile inside a serving tick lands
+    under that `engine.tick`)."""
+    def on(event: str, secs: float, **_kw) -> None:
+        if event == _BACKEND_COMPILE:
+            now = time.perf_counter()
+            SPANS.record("engine.compile", now - secs, now)
+    jax.monitoring.register_event_duration_secs_listener(on)
 
 
 @dataclass
@@ -52,14 +70,29 @@ class ServeEngine:
             self.tokens = self._put(jnp.zeros((batch_slots, 1), jnp.int32))
         self.slot_req: List[Optional[Request]] = [None] * batch_slots
         self.queue: "collections.deque[Request]" = collections.deque()
-        self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
+        self._queued_at: Dict[int, float] = {}    # id(req) -> add_request
+        self._decode = jax.jit(self._decode_step, donate_argnums=(1,))
         self._prefill_one = jax.jit(self._prefill_impl)
         self._completed: List[Request] = []
         self.stats = {"ticks": 0, "prefills": 0, "decoded_tokens": 0,
                       "completed": 0}
+        _record_compiles()
+
+    def _decode_step(self, params, cache, batch):
+        """The batched decode step, jitted under this name (a profile
+        names the program `jit__decode_step`)."""
+        return self.model.decode_step(params, cache, batch)
 
     def _prefill_impl(self, params, tokens):
         return self.model.prefill(params, {"tokens": tokens})
+
+    @staticmethod
+    def _read(x) -> np.ndarray:
+        """Every device-to-host read of the engine goes through here,
+        counted as one host sync of the open `engine.tick` and
+        `engine.admit` spans."""
+        SPANS.add("syncs")
+        return np.asarray(x)
 
     def _put(self, x):
         """Commit a host value (or pytree) to this engine's device."""
@@ -68,6 +101,7 @@ class ServeEngine:
     # -- request management ------------------------------------------------------
 
     def add_request(self, req: Request):
+        self._queued_at[id(req)] = time.perf_counter()
         self.queue.append(req)
 
     @property
@@ -96,15 +130,24 @@ class ServeEngine:
             if self.slot_req[slot] is not None or not self.queue:
                 continue
             req = self.queue.popleft()
-            prompt = self._put(np.asarray(req.prompt, np.int32)[None, :])
+            now = time.perf_counter()
+            SPANS.record("engine.queue", self._queued_at.pop(id(req), now),
+                         now, req=req.id)
+            with SPANS.span("engine.admit", req=req.id,
+                            prompt=len(req.prompt), syncs=0):
+                self._admit(req, slot)
+
+    def _admit(self, req: Request, slot: int):
+        prompt = self._put(np.asarray(req.prompt, np.int32)[None, :])
+        with SPANS.span("engine.prefill"):
             logits, pcache = self._prefill_one(self.params, prompt)
-            next_tok = int(jnp.argmax(logits[0, -1]))
-            req.output.append(next_tok)
-            self._scatter_cache(pcache, slot, len(req.prompt))
-            self.positions = self.positions.at[slot].set(len(req.prompt))
-            self.tokens = self.tokens.at[slot, 0].set(next_tok)
-            self.slot_req[slot] = req
-            self.stats["prefills"] += 1
+        next_tok = int(self._read(jnp.argmax(logits[0, -1])))
+        req.output.append(next_tok)
+        self._scatter_cache(pcache, slot, len(req.prompt))
+        self.positions = self.positions.at[slot].set(len(req.prompt))
+        self.tokens = self.tokens.at[slot, 0].set(next_tok)
+        self.slot_req[slot] = req
+        self.stats["prefills"] += 1
 
     def _scatter_cache(self, pcache, slot: int, plen: int):
         """Copy a 1-seq prefill cache into batch slot `slot`."""
@@ -121,13 +164,19 @@ class ServeEngine:
 
     def tick(self) -> int:
         """One engine iteration; returns number of active slots decoded."""
+        with SPANS.span("engine.tick", active=0, syncs=0) as span:
+            span.attrs["active"] = n = self._tick()
+        return n
+
+    def _tick(self) -> int:
         self._fill_free_slots()
         active = [s for s in range(self.B) if self.slot_req[s] is not None]
         if not active:
             return 0
         batch = {"tokens": self.tokens, "positions": self.positions}
-        logits, self.cache = self._decode(self.params, self.cache, batch)
-        next_tokens = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
+        with SPANS.span("engine.decode"):
+            logits, self.cache = self._decode(self.params, self.cache, batch)
+        next_tokens = self._read(jnp.argmax(logits[:, -1, :], axis=-1))
         self.positions = self.positions + 1
         self.stats["ticks"] += 1
         for s in active:
@@ -136,7 +185,8 @@ class ServeEngine:
             req.output.append(tok)
             self.stats["decoded_tokens"] += 1
             limit = len(req.output) >= req.max_new_tokens
-            if tok == req.eos_id or limit or int(self.positions[s]) >= self.max_len - 1:
+            if (tok == req.eos_id or limit
+                    or int(self._read(self.positions[s])) >= self.max_len - 1):
                 req.done = True
                 self.slot_req[s] = None
                 self._completed.append(req)

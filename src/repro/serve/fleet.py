@@ -24,6 +24,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterator, List, Sequence
 
 from repro.core.cluster import SyndeoCluster
+from repro.core.metrics import SPANS
 from repro.core.rendezvous import FileRendezvous
 from repro.core.worker import HeadServer, _dec, _enc, _request, run_worker
 from repro.serve.router import ActorReplicaHandle, ReplicaActor, Router
@@ -44,13 +45,20 @@ def actor_caller(rpc: Callable[[Dict[str, Any]], Dict[str, Any]],
     whose create failed) raises with the head's or the worker's error
     text."""
     def call(payload: Dict[str, Any]) -> Any:
+        with SPANS.span("wire.call", kind=payload.get("kind"),
+                        polls=0) as span:
+            return round_trip(payload, span.attrs)
+
+    def round_trip(payload: Dict[str, Any], attrs: Dict[str, Any]) -> Any:
         sent = rpc({"op": "actor_call", "actor": actor, "cap": cap,
                     "payload": _enc(payload)})
         if not sent.get("ok"):
             raise RuntimeError(f"replica {actor!r}: {sent.get('error')}")
+        attrs["call"] = sent["call"]
         limit = time.monotonic() + timeout_s
         while time.monotonic() < limit:
             got = rpc({"op": "actor_result", "call": sent["call"]})
+            attrs["polls"] += 1
             if got.get("done"):
                 if got.get("error"):
                     raise RuntimeError(f"replica {actor!r}: {got['error']}")

@@ -51,7 +51,7 @@ import collections
 import time
 from typing import Any, Callable, Dict, List, Optional
 
-from repro.core.metrics import MetricsRegistry
+from repro.core.metrics import SPANS, MetricsRegistry
 from repro.serve.engine import Request, StubEngine
 
 
@@ -165,6 +165,10 @@ class Router:
         """Admit one request. True = accepted (placed now, or parked in
         the retry buffer); False = shed (every replica AND the retry
         buffer are full -- the caller may retry later)."""
+        with SPANS.span("router.submit", req=req.id):
+            return self._submit(req)
+
+    def _submit(self, req: Request) -> bool:
         self._submit_t[req.id] = self.clock()
         if self._place(req) is not None:
             self.stats["requests"] += 1
@@ -212,6 +216,10 @@ class Router:
         """One router iteration: re-admit the retry buffer into freed
         capacity, tick every replica one decode step, harvest
         completions. Returns the requests that finished this tick."""
+        with SPANS.span("router.tick"):
+            return self._tick()
+
+    def _tick(self) -> List[Request]:
         for _ in range(len(self._retry)):
             req = self._retry.popleft()
             if self._place(req) is None:

@@ -1,0 +1,130 @@
+"""Spans of the served path, recorded where the work happens: the parts
+of one actor round trip (client, head, worker), and the engine's queue,
+admissions, host syncs and compiles."""
+import time
+
+import jax
+import pytest
+
+from repro.configs import get_config
+from repro.core.metrics import SPANS
+from repro.models import build_model
+from repro.serve.engine import Request, ServeEngine, StubEngine
+from repro.serve.fleet import serve_fleet
+
+CALL_PARTS = ("head.outbox", "actor.handle", "head.held")
+
+
+def _since(t0):
+    return [s for s in SPANS.spans() if s.start >= t0]
+
+
+def test_each_tick_call_is_split_into_its_parts_in_time_order():
+    t0 = time.perf_counter()
+    reqs = [Request(id=i, prompt=[i + 1, 2], max_new_tokens=3)
+            for i in range(5)]
+    with serve_fleet([StubEngine(batch_slots=2)]) as fleet:
+        for r in reqs:
+            assert fleet.router.submit(r)
+        fleet.router.flush()
+    assert all(r.done for r in reqs)
+    spans = _since(t0)
+    assert SPANS.horizon < t0
+    ticks = [s for s in spans
+             if s.name == "wire.call" and s.attrs["kind"] == "tick"]
+    assert ticks
+    for call in ticks:
+        cid = call.attrs["call"]
+        parts = {}
+        for name in CALL_PARTS:
+            (parts[name],) = [s for s in spans if s.name == name
+                              and s.attrs["call"] == cid]
+        out, handle, held = (parts[n] for n in CALL_PARTS)
+        assert handle.attrs["kind"] == "tick"
+        assert handle.thread != call.thread
+        assert (call.start <= out.start <= out.end <= handle.start
+                <= handle.end <= held.start <= held.end <= call.end)
+        waits = (out.end - out.start) + (held.start - handle.end) \
+            + (held.end - held.start)
+        assert waits <= call.end - call.start
+        assert call.attrs["polls"] >= 1
+    submits = [s for s in spans if s.name == "router.submit"]
+    assert sorted(s.attrs["req"] for s in submits) == [r.id for r in reqs]
+    assert len([s for s in spans if s.name == "router.tick"]) \
+        == fleet.router.stats["ticks"]
+
+
+@pytest.fixture(scope="module")
+def smoke_model():
+    cfg = get_config("llama3-8b", smoke=True)
+    model = build_model(cfg)
+    return model, jax.jit(model.init_params)(jax.random.PRNGKey(0))
+
+
+def _engine(smoke_model, slots=2):
+    model, params = smoke_model
+    return ServeEngine(model, params, batch_slots=slots, max_len=32)
+
+
+def test_engine_counts_every_host_read_in_its_tick(smoke_model):
+    engine = _engine(smoke_model)
+    reads = []
+    read = engine._read
+
+    def counted(x):
+        reads.append(1)
+        return read(x)
+
+    engine._read = counted
+    reqs = [Request(id=100 + i, prompt=list(range(1, 2 + i)),
+                    max_new_tokens=2 + i) for i in range(4)]
+    t0 = time.perf_counter()
+    for r in reqs:
+        engine.add_request(r)
+    engine.run_until_drained()
+    spans = _since(t0)
+    ticks = [s for s in spans if s.name == "engine.tick"]
+    assert len(ticks) == engine.stats["ticks"]
+    assert sum(s.attrs["syncs"] for s in ticks) == len(reads)
+    for tick in ticks:
+        admits = [s for s in spans
+                  if s.name == "engine.admit" and s.parent == tick.id]
+        assert all(a.attrs["syncs"] == 1 for a in admits)
+        # one argmax read, one per admission, at most one per slot
+        lo = 1 + len(admits)
+        assert lo <= tick.attrs["syncs"] <= lo + tick.attrs["active"]
+    for name in ("engine.queue", "engine.admit"):
+        ids = sorted(s.attrs["req"] for s in spans if s.name == name)
+        assert ids == [r.id for r in reqs]
+    admit = {s.attrs["req"]: s for s in spans if s.name == "engine.admit"}
+    for s in spans:
+        if s.name == "engine.queue":
+            assert s.end <= admit[s.attrs["req"]].start
+    assert {a.attrs["prompt"] for a in admit.values()} == {1, 2, 3, 4}
+
+
+def test_compile_inside_a_tick_is_recorded_under_it(smoke_model):
+    engine = _engine(smoke_model)
+    t0 = time.perf_counter()
+    # a prompt length no other test uses: its prefill has to compile
+    engine.add_request(Request(id=1, prompt=[3] * 11, max_new_tokens=1))
+    engine.tick()
+    spans = _since(t0)
+    by_id = {s.id: s for s in spans}
+    (tick,) = [s for s in spans if s.name == "engine.tick"]
+    compiles = [s for s in spans if s.name == "engine.compile"]
+    assert compiles
+    for c in compiles:
+        chain, p = [], c.parent
+        while p is not None:
+            chain.append(by_id[p].name)
+            p = by_id[p].parent
+        assert "engine.tick" in chain
+        assert tick.start <= c.start <= c.end <= tick.end
+
+
+def test_decode_step_program_is_named_after_it(smoke_model):
+    engine = _engine(smoke_model)
+    batch = {"tokens": engine.tokens, "positions": engine.positions}
+    text = engine._decode.lower(engine.params, engine.cache, batch).as_text()
+    assert text.startswith("module @jit__decode_step ")
