@@ -36,6 +36,10 @@ replay protection, head TCP port):
                                  relay: task, payload=(fn, args, kwargs,
                                         dep values), tenant, draining
                                  idle:  task=None, draining
+                               over TCP the head holds a poll whose reply
+                               would be empty until something is queued
+                               for the worker (at most 50 ms); a held
+                               poll's reply carries waited=<seconds held>
                                a draining p2p worker's reply may carry
                                migrations=[{ref, size, node, host, port,
                                ticket}]: direct-push drain directives the
@@ -136,7 +140,8 @@ drain of their node completes only after every replica exits.
   actor_result worker -> head  worker, actor, call, value|error -- a
                                finished call, riding the batch frame
                client -> head  call (no worker field) -- fetch one
-                               result -> done, value|error
+                               result; over TCP held at the head until
+                               it is in (at most 1 s) -> done, value|error
   actor_exit   client -> head  actor, cap -- graceful exit request,
                                queued as a directive; the replica
                                finishes in-flight work first
@@ -551,7 +556,20 @@ class HeadServer:
 
     `head_payload_bytes` counts data-plane payload bytes that transited
     the head's control socket (dep values + result pickles in relay
-    mode); the CI dataplane smoke asserts it stays 0 under p2p."""
+    mode); the CI dataplane smoke asserts it stays 0 under p2p.
+
+    Long polls: over the wire, a poll whose reply would carry nothing is
+    held until something is queued for its worker, at most
+    `POLL_HOLD_S`; a client's `actor_result` is held until the result is
+    stored, at most `RESULT_WAIT_CAP_S`. Both wait outside the cluster
+    lock."""
+
+    # the longest an empty poll is held: the pause a worker used to take
+    # between polls, so an idle worker still polls ~20 times a second
+    POLL_HOLD_S = 0.05
+    # the longest a client's actor_result is held, well under `_request`'s
+    # socket timeout
+    RESULT_WAIT_CAP_S = 1.0
 
     def __init__(self, cluster: SyndeoCluster, host: str = "127.0.0.1",
                  port: int = 0, data_plane: Optional[str] = None,
@@ -584,6 +602,13 @@ class HeadServer:
         # the actor outbox (`head.outbox` span), then its result held for
         # the client (`head.held` span)
         self._call_wait: Dict[str, float] = {}
+        # held requests wait on `_arrivals`; `_arrived` counts, per
+        # worker, what was queued for it and changes only under the
+        # condition, so a poll that reads its count before looking at its
+        # queues cannot miss an arrival that lands in between.
+        self._arrivals = threading.Condition()
+        self._arrived: Dict[str, int] = {}
+        self._closing = False
         self.serve_stats: Dict[str, float] = {}
         # observability hub: shares the scheduler's registry (sojourn
         # histograms land there) and folds worker-pushed histogram
@@ -617,6 +642,16 @@ class HeadServer:
                 orig_failed(worker_id, reason)
 
             cluster.scheduler.on_worker_failed = on_failed
+        # a drain that begins changes what the worker's next poll says
+        orig_drain = cluster.scheduler.begin_drain
+
+        def begin_drain(worker_id, deadline_s=None):
+            ok = orig_drain(worker_id, deadline_s)
+            if ok:
+                self._wake(worker_id)
+            return ok
+
+        cluster.scheduler.begin_drain = begin_drain
         head = self
 
         class Handler(socketserver.StreamRequestHandler):
@@ -626,7 +661,7 @@ class HeadServer:
                     msg = open_sealed(cluster.token,
                                       json.loads(line.decode()),
                                       nonce_cache=head._nonces)
-                    reply = head.dispatch(msg)
+                    reply = head.dispatch(msg, hold=True)
                 except Exception as e:  # noqa: BLE001
                     reply = {"ok": False, "error": str(e)}
                 self.wfile.write(
@@ -676,6 +711,7 @@ class HeadServer:
             # notices race the notice window, so the source worker
             # batches and orders its pushes deadline-soonest-first
             "deadline_s": c.scheduler.drain_deadline_s(worker_id)})
+        self._wake(worker_id)
 
     def _migrate_relay(self, worker_id: str, ref: ObjectRef, dst: str):
         """Head-relayed move on a background thread (the blocking
@@ -859,6 +895,7 @@ class HeadServer:
         self._actor_results[str(msg["call"])] = {
             "actor": msg.get("actor"), "host": msg.get("worker"),
             "value": msg.get("value"), "error": msg.get("error")}
+        self._wake()
         return {"ok": True}
 
     def _handle_actor_exited(self, msg: Dict[str, Any]) -> Dict[str, Any]:
@@ -875,7 +912,52 @@ class HeadServer:
             self._actor_create_errors[aid] = str(msg["error"])
         return {"ok": True, "released": released}
 
-    def dispatch(self, msg: Dict[str, Any]) -> Dict[str, Any]:
+    def _wake(self, worker_id: Optional[str] = None) -> None:
+        """Something was queued for `worker_id`'s next poll (None: an
+        actor result was stored): wake the requests held for it."""
+        with self._arrivals:
+            if worker_id is not None:
+                self._arrived[worker_id] = self._arrived.get(worker_id, 0) + 1
+            self._arrivals.notify_all()
+
+    def _hold_empty_poll(self, wid: str
+                         ) -> Optional[Tuple[float, float, int]]:
+        """Hold a poll whose reply would carry nothing -- no task, no
+        actor directive, no drain move, not draining -- until something
+        is queued for `wid` or `POLL_HOLD_S` runs out. Returns the wait's
+        (start, end, woke), woke 1 when an arrival ended it, or None
+        where the poll is not held."""
+        c = self.cluster
+        with self._arrivals:
+            seen = self._arrived.get(wid, 0)
+        with c._lock:
+            w = c.scheduler.workers.get(wid)
+            if (self._closing or w is None or w.draining
+                    or self._outbox.get(wid) or self._actor_outbox.get(wid)
+                    or (wid in self._blob_eps
+                        and self._pending_migrations.get(wid))):
+                return None
+        start = time.perf_counter()
+        with self._arrivals:
+            self._arrivals.wait_for(
+                lambda: self._closing or self._arrived.get(wid, 0) != seen,
+                self.POLL_HOLD_S)
+            woke = int(self._arrived.get(wid, 0) != seen)
+        return start, time.perf_counter(), woke
+
+    def _await_result(self, call_id: str) -> None:
+        """Hold a client's actor_result until `call_id`'s result is
+        stored, at most `RESULT_WAIT_CAP_S`."""
+        with self._arrivals:
+            self._arrivals.wait_for(
+                lambda: self._closing or call_id in self._actor_results,
+                self.RESULT_WAIT_CAP_S)
+
+    def dispatch(self, msg: Dict[str, Any],
+                 hold: bool = False) -> Dict[str, Any]:
+        """Serve one verified message. `hold` (the TCP handler's requests)
+        lets an empty poll or a client's actor_result wait for what it
+        asks for; in-process callers are never held."""
         op = msg.get("op")
         c = self.cluster
         if op == "join":
@@ -900,6 +982,7 @@ class HeadServer:
             return {"ok": True, "worker": wid, "data_plane": plane}
         if op == "poll":
             wid = msg["worker"]
+            wait = self._hold_empty_poll(wid) if hold else None
             with c._lock:
                 c.scheduler.heartbeat(wid)
                 w = c.scheduler.workers.get(wid)
@@ -951,12 +1034,19 @@ class HeadServer:
             for call_id, queued in handed:
                 if queued is not None:
                     SPANS.record("head.outbox", queued, now, call=call_id)
+            if wait is not None:
+                SPANS.record("head.poll_wait", wait[0], wait[1],
+                             woke=wait[2], calls=[cid for cid, _ in handed])
 
             def with_moves(reply: Dict[str, Any]) -> Dict[str, Any]:
                 if moves:
                     reply["migrations"] = moves
                 if acts:
                     reply["actor_ops"] = acts
+                if wait is not None:
+                    # the worker needs no pause after a poll the head held
+                    # (and leaves the hold out of its poll latency)
+                    reply["waited"] = wait[1] - wait[0]
                 return reply
 
             box = self._outbox.get(wid, [])
@@ -1010,9 +1100,10 @@ class HeadServer:
                     ev = c._futures.get(tid)
                     if ev:
                         ev.set()
-                    return {"ok": True, "task": None, "draining": draining}
-            return {"ok": True, "task": tid, "payload": payload,
-                    "tenant": tenant, "draining": draining}
+                    return with_moves({"ok": True, "task": None,
+                                       "draining": draining})
+            return with_moves({"ok": True, "task": tid, "payload": payload,
+                               "tenant": tenant, "draining": draining})
         if op == "result_meta":
             return self._handle_result_meta(msg)
         if op == "result":
@@ -1107,6 +1198,7 @@ class HeadServer:
                 self._actor_outbox.setdefault(wid, []).append(
                     {"op": "actor_create", "actor": aid, "factory": factory,
                      "kwargs": msg.get("kwargs") or {}, "tenant": tenant})
+                self._wake(wid)
             cap = Capability.grant_actor(c.token, tenant, aid)
             return {"ok": True, "actor": aid, "worker": wid,
                     "cap": {"object_id": cap.object_id, "right": cap.right,
@@ -1138,12 +1230,15 @@ class HeadServer:
                 self._actor_outbox.setdefault(info.worker_id, []).append(
                     {"op": "actor_call", "actor": aid, "call": call_id,
                      "payload": msg.get("payload")})
+                self._wake(info.worker_id)
             return {"ok": True, "call": call_id, "worker": info.worker_id}
         if op == "actor_result":
             if msg.get("worker"):      # worker-side completion report
                 with c._lock:
                     return self._handle_actor_result(msg)
             call_id = str(msg["call"])
+            if hold:
+                self._await_result(call_id)
             res = self._actor_results.pop(call_id, None)
             if res is None:
                 return {"ok": True, "done": False}
@@ -1176,6 +1271,7 @@ class HeadServer:
                     self._actor_outbox.setdefault(info.worker_id,
                                                   []).append(
                         {"op": "actor_exit", "actor": aid})
+                    self._wake(info.worker_id)
             return {"ok": True, "exited": False}
         if op == "ticket":
             # mid-fetch re-mint: a task with many fat deps can outlive the
@@ -1368,7 +1464,7 @@ class HeadServer:
                                       "error": f"{type(e).__name__}: {e}"}
             for i in deferred:
                 try:
-                    replies[i] = self.dispatch(subs[i])
+                    replies[i] = self.dispatch(subs[i], hold)
                 except Exception as e:  # noqa: BLE001
                     replies[i] = {"ok": False,
                                   "error": f"{type(e).__name__}: {e}"}
@@ -1453,6 +1549,7 @@ class HeadServer:
 
     def launch(self, task, worker_id: str):
         self._outbox.setdefault(worker_id, []).append(task.id)
+        self._wake(worker_id)
 
     def attach(self):
         """Route scheduler launches for tcp- workers through the outbox."""
@@ -1466,6 +1563,9 @@ class HeadServer:
         self.cluster.scheduler.launch_fn = launch
 
     def shutdown(self):
+        with self._arrivals:           # nothing held outlives the head
+            self._closing = True
+            self._arrivals.notify_all()
         self.server.shutdown()
         self.server.server_close()   # release the listening socket fd
         if self._blob_srv is not None:
@@ -2041,8 +2141,13 @@ def run_worker(rendezvous_dir: str, cluster_id: str, worker_id: str = "",
                 got = _request(ep.host, ep.port, token, req,
                                nonce_cache=nonces)
                 # observed AFTER the frame was built: this round trip's
-                # latency rides the NEXT frame (or the exit flush)
-                poll_hist.observe(time.monotonic() - poll_t0)
+                # latency rides the NEXT frame (or the exit flush); the
+                # time the head held an empty poll is not latency
+                rtt = time.monotonic() - poll_t0
+                polled = ((got.get("replies") or [{}])[-1]
+                          if sent or flush_due else got)
+                poll_hist.observe(
+                    max(0.0, rtt - float(polled.get("waited") or 0.0)))
             except OSError:
                 # same tolerance as the leave handshake: one refused
                 # connect (listen-backlog burst, transient timeout) must
@@ -2095,7 +2200,11 @@ def run_worker(rendezvous_dir: str, cluster_id: str, worker_id: str = "",
                         # the last polls' latencies) must not die with us
                         flush_metrics()
                         return
-                time.sleep(0.05)
+                if not (pending_ops or got.get("waited") is not None):
+                    # nothing queued and the head did not hold the poll:
+                    # pause before the next one. Queued results (a
+                    # replica's answer) ride the next frame at once.
+                    time.sleep(0.05)
                 continue
             run_task(tid, got)
             # the idle clock starts *after* completion: a long task's next

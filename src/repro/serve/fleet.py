@@ -57,13 +57,14 @@ def actor_caller(rpc: Callable[[Dict[str, Any]], Dict[str, Any]],
         attrs["call"] = sent["call"]
         limit = time.monotonic() + timeout_s
         while time.monotonic() < limit:
+            # the head holds the request until the result is stored (for
+            # at most its cap), so one request normally brings it back
             got = rpc({"op": "actor_result", "call": sent["call"]})
             attrs["polls"] += 1
             if got.get("done"):
                 if got.get("error"):
                     raise RuntimeError(f"replica {actor!r}: {got['error']}")
                 return _dec(got["value"])
-            time.sleep(0.01)
         raise TimeoutError(f"replica {actor!r}: no result for "
                            f"{payload.get('kind')!r} in {timeout_s} s")
     return call
