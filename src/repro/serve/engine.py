@@ -2,8 +2,9 @@
 
 Fixed B decode slots over a static-shaped KV cache (TPU-friendly: one
 compiled decode step, no re-compilation as requests come and go):
-  * new requests are prefilled one-at-a-time (padded to the prefill bucket)
-    and their cache scattered into a free slot,
+  * new requests are prefilled one at a time, at their own prompt length
+    (one prefill program is compiled per length), and their cache
+    scattered into a free slot,
   * every engine tick decodes all active slots in one batched step,
   * finished slots (EOS or max_len) are freed and refilled from the queue.
 
@@ -126,6 +127,9 @@ class ServeEngine:
         return owed
 
     def _fill_free_slots(self):
+        """Admit queued requests into empty slots. An admission's `held` is
+        the number of slots already answering (those admitted earlier in
+        this tick among them): each gets no token until it ends."""
         for slot in range(self.B):
             if self.slot_req[slot] is not None or not self.queue:
                 continue
@@ -133,8 +137,9 @@ class ServeEngine:
             now = time.perf_counter()
             SPANS.record("engine.queue", self._queued_at.pop(id(req), now),
                          now, req=req.id)
+            held = sum(r is not None for r in self.slot_req)
             with SPANS.span("engine.admit", req=req.id,
-                            prompt=len(req.prompt), syncs=0):
+                            prompt=len(req.prompt), syncs=0, held=held):
                 self._admit(req, slot)
 
     def _admit(self, req: Request, slot: int):

@@ -103,6 +103,31 @@ def test_engine_counts_every_host_read_in_its_tick(smoke_model):
     assert {a.attrs["prompt"] for a in admit.values()} == {1, 2, 3, 4}
 
 
+def _held(t0, ids):
+    return [s.attrs["held"] for s in _since(t0)
+            if s.name == "engine.admit" and s.attrs["req"] in ids]
+
+
+def test_admission_counts_the_slots_it_holds(smoke_model):
+    engine = _engine(smoke_model)
+    t0 = time.perf_counter()
+    engine.add_request(Request(id=201, prompt=[1, 2], max_new_tokens=6))
+    engine.tick()                       # into an idle engine
+    assert _held(t0, {201}) == [0]
+    engine.add_request(Request(id=202, prompt=[3, 4], max_new_tokens=2))
+    engine.tick()                       # 201 is mid-answer
+    assert _held(t0, {202}) == [1]
+    engine.run_until_drained()
+    # two admitted in one tick: the first is answering when the second is
+    engine = _engine(smoke_model)
+    t0 = time.perf_counter()
+    for i in (203, 204):
+        engine.add_request(Request(id=i, prompt=[5, 6], max_new_tokens=2))
+    engine.tick()
+    assert _held(t0, {203, 204}) == [0, 1]
+    engine.run_until_drained()
+
+
 def test_compile_inside_a_tick_is_recorded_under_it(smoke_model):
     engine = _engine(smoke_model)
     t0 = time.perf_counter()
