@@ -79,7 +79,8 @@ Cells chosen per spec: worst roofline cell (arctic-480b x train_4k:
 over-memory + biggest model), most representative dense training
 (llama3-8b x train_4k), and the serving shape Syndeo fleets run at scale
 (qwen1.5-32b x decode_32k). Baseline = paper-faithful implementation
-(tag `baseline`, flags `flash_vjp=False, direct_cache=False`); optimized
+(tag `baseline`, flag `flash_vjp=False`; its `direct_cache=False` flag
+went with the direct path, below); optimized
 variants are cumulative and live under their own tags. Stopping rule: three
 consecutive <5% changes on the dominant term, or term moved below the next
 one.
@@ -114,7 +115,7 @@ fits 9.5 GiB/chip. Iterations stopped after two refuted follow-ups (<5% rule).
 | 0 | baseline (int8 KV + 48-head padding + serve-FSDP, in-place carry cache) | -- | {qwen_base} | memory-dominant (decode physics) |
 | 1 | bf16 dequantization of int8 blocks | dequant intermediates halve | no change | **REFUTED -- usefully**: the dequant already fuses into the attention dot (boundary-bytes model unchanged); it would not touch HBM on TPU either |
 | 2 | block_k 1024 -> 2048 | fewer loop-boundary buffers | no change | refuted (slice totals identical) |
-| 3 | direct-indexed 5D-cache attention (no per-layer take/put copies) | cache read drops ~3x -> 1x | {qwen_it3} | **REFUTED at the XLA level**: traced-index scatter breaks while-carry aliasing; the cache is copied per layer (memory 0.32 -> 2.29 s). Reverted; kept selectable for the record. |
+| 3 | direct-indexed 5D-cache attention (no per-layer take/put copies) | cache read drops ~3x -> 1x | {qwen_it3} | **REFUTED at the XLA level**: traced-index scatter breaks while-carry aliasing; the cache is copied per layer (memory 0.32 -> 2.29 s). Reverted; the path and its `direct_cache` flag have since been deleted: the decode step reads the stacked cache in place through the Pallas kernel. |
 | -- | modeled: Pallas decode kernel (kernels/decode_attention.py) | cache streamed exactly once from HBM, dequant in VMEM | floor = (13.3 GB int8 cache + 0.4 GB scales + 0.25 GB weights)/819 GB/s = **17 ms** vs 323 ms parsed XLA-path | kernel validated (incl. int8 path) vs oracle; modeled |
 
 Net: the honest XLA-path number is the baseline 0.323 s; the implemented and

@@ -28,10 +28,6 @@ ITERATIONS = [
     ("arctic-480b", "train_4k", "it3_bf16accum",
      {"flash_vjp": True, "rules": {"seq": ("model",)},
       "accum_dtype": "bfloat16"}),
-    ("qwen1.5-32b", "decode_32k", "it1_bf16dequant",
-     {"dequant_dtype": "bfloat16"}),
-    ("qwen1.5-32b", "decode_32k", "it2_blocks",
-     {"dequant_dtype": "bfloat16", "decode_block_k": 2048}),
 ]
 
 

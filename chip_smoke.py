@@ -122,10 +122,13 @@ def kernel_cases(dense: ModelConfig, moe: ModelConfig, ssm: ModelConfig,
     q = rand((1, hq, seq_len, hd))
     k = rand((1, dense.n_kv_heads, seq_len, hd))
     v = rand((1, dense.n_kv_heads, seq_len, hd))
+    hkv = dense.cache_kv_heads
     dq = rand((slots, hq, hd))
-    dk = rand((slots, dense.cache_kv_heads, max_len, hd))
-    dv = rand((slots, dense.cache_kv_heads, max_len, hd))
-    vl = jnp.linspace(1, max_len, slots).astype(jnp.int32)
+    dkn, dvn = rand((slots, hkv, hd)), rand((slots, hkv, hd))
+    dk = rand((2, slots, max_len, hkv * hd))      # two stacked layers
+    dv = rand((2, slots, max_len, hkv * hd))
+    layer = jnp.int32(1)
+    pos = jnp.linspace(0, max_len, slots).astype(jnp.int32)
     e = moe.moe.n_experts
     gx = rand((e, min(128, seq_len), moe.d_model))
     gw = rand((e, moe.d_model, moe.d_ff), scale=moe.d_model ** -0.5)
@@ -143,9 +146,9 @@ def kernel_cases(dense: ModelConfig, moe: ModelConfig, ssm: ModelConfig,
             ops.flash_attention, (q, k, v), {},
             lambda: ref.attention_ref(q, k, v, causal=True)),
         "decode_attention": (
-            ops.decode_attention, (dq, dk, dv, vl), {},
-            lambda: ref.attention_ref(dq[:, :, None], dk, dv, causal=False,
-                                      valid_len=vl)[:, :, 0]),
+            ops.decode_attention, (dq, dkn, dvn, dk, dv, layer, pos), {},
+            lambda: ref.decode_attention_ref(dq, dkn, dvn, dk, dv, layer,
+                                             pos)),
         "moe_gmm": (ops.moe_gmm, (gx, gw), {},
                     lambda: ref.moe_gmm_ref(gx, gw)),
         "ssd_scan": (

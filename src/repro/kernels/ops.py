@@ -34,11 +34,13 @@ def flash_attention(q, k, v, *, causal=True, window=None,
                   block_k=block_k, interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("block_k",))
-def decode_attention(q, k, v, valid_len, k_scale=None, v_scale=None,
-                     *, block_k=512):
-    """q (B,Hq,D); k/v cache (B,Hkv,S,D) [+int8 scales]; valid_len (B,)."""
-    return _decode(q, k, v, valid_len, k_scale=k_scale, v_scale=v_scale,
+@partial(jax.jit, static_argnames=("window", "block_k"))
+def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, pos,
+                     k_scale=None, v_scale=None, *, window=None, block_k=None):
+    """q (B,Hq,D); this step's k_new/v_new (B,Hkv,D); the stacked cache
+    (L,B,S,Hkv*D) [+int8 scales (L,B,Hkv,S)]; layer; pos (B,)."""
+    return _decode(q, k_new, v_new, k_cache, v_cache, layer, pos,
+                   k_scale=k_scale, v_scale=v_scale, window=window,
                    block_k=block_k, interpret=_interpret())
 
 

@@ -42,6 +42,37 @@ def attention_ref(q, k, v, *, causal=True, window: Optional[int] = None,
     return jnp.einsum("bhqk,bhkd->bhqd", p, vf).astype(q.dtype)
 
 
+def decode_attention_ref(q, k_new, v_new, k_cache, v_cache, layer, pos, *,
+                         k_scale=None, v_scale=None,
+                         window: Optional[int] = None):
+    """Decode attention oracle: q (B,Hq,D) over layer `layer` of the stacked
+    cache (L,B,S,Hkv*D) [+ int8 scales (L,B,Hkv,S)], rows [pos - window + 1,
+    min(pos, S)), plus the step's own k_new/v_new (B,Hkv,D) at pos."""
+    B, Hq, D = q.shape
+    Hkv = k_new.shape[1]
+    S = k_cache.shape[2]
+    f32 = jnp.float32
+    kc = k_cache[layer].reshape(B, S, Hkv, D).astype(f32)
+    vc = v_cache[layer].reshape(B, S, Hkv, D).astype(f32)
+    if k_scale is not None:
+        kc = kc * k_scale[layer].transpose(0, 2, 1)[..., None]
+        vc = vc * v_scale[layer].transpose(0, 2, 1)[..., None]
+    k = jnp.concatenate([kc, k_new[:, None].astype(f32)], axis=1)
+    v = jnp.concatenate([vc, v_new[:, None].astype(f32)], axis=1)
+    kpos = jnp.arange(S + 1)[None, :]
+    pos = pos[:, None]
+    keep = kpos < jnp.minimum(pos, S)
+    if window is not None:
+        keep &= kpos > pos - window
+    keep |= kpos == S                                   # the new row
+    qg = q.reshape(B, Hkv, Hq // Hkv, D).astype(f32)
+    s = jnp.einsum("bhrd,bkhd->bhrk", qg, k) / math.sqrt(D)
+    s = jnp.where(keep[:, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhrk,bkhd->bhrd", p, v)
+    return out.reshape(B, Hq, D).astype(q.dtype)
+
+
 def moe_gmm_ref(x, w):
     """Grouped matmul oracle. x (E, C, d) @ w (E, d, f) -> (E, C, f)."""
     return jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32),

@@ -116,13 +116,15 @@ def grad_shardings(params_shapes, cfg: ModelConfig, mesh, rules, dp_axes):
 
 
 def cache_shardings(cshapes, cfg: ModelConfig, mesh, rules, global_batch: int):
-    """KV caches: (layers, batch, seq, cache_kv_heads, hd) -> shard batch over
-    the DP axes and the *heads* dim over model (KV replication/padding in the
-    configs guarantees divisibility). Recurrent states: shard batch only."""
+    """KV caches: (layers, batch, seq, cache_kv_heads * hd), int8 scales
+    (layers, batch, cache_kv_heads, seq) -> shard batch over the DP axes and
+    the *heads* over model (KV replication/padding in the configs guarantees
+    divisibility). Recurrent states: shard batch only."""
     from repro.sharding.axes import _guard_divisibility
     batch_axes = rules.get("batch", ())
     model_axes = rules.get("model", ())
-    head_dims = {cfg.cache_kv_heads, cfg.eff_kv_heads}
+    head_dims = {cfg.cache_kv_heads, cfg.eff_kv_heads,
+                 cfg.cache_kv_heads * cfg.resolved_head_dim}
 
     def per_leaf(path, leaf):
         spec = [None] * len(leaf.shape)
@@ -159,13 +161,8 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                overrides: Optional[Dict[str, Any]] = None):
     """Lower + compile one cell; returns (lowered, compiled, meta)."""
     overrides = overrides or {}
-    import jax.numpy as _jnp
     import repro.models.layers as _L
     _L.FLASH_VJP = overrides.get("flash_vjp", True)
-    _L.DEQUANT_DTYPE = _jnp.dtype(overrides.get("dequant_dtype", "float32"))
-    _L.DECODE_BLOCK_K = overrides.get("decode_block_k", 1024)
-    import repro.models.dense as _D
-    _D.DIRECT_CACHE_DECODE = overrides.get("direct_cache", True)
     cfg = get_config(arch)
     for k, v in overrides.get("cfg", {}).items():
         cfg = cfg.replace(**{k: v})

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.models import layers as L
 from repro.models.moe import init_moe_layer, moe_ffn
 from repro.sharding.axes import constrain
@@ -128,24 +129,18 @@ def _kv_cache_dtype(cfg: ModelConfig):
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int):
+    """k/v: (layers, batch, max_len, kv_heads * head_dim), a token's heads
+    side by side (the layout the decode kernel reads in place); int8 scales:
+    (layers, batch, kv_heads, max_len)."""
     hd = cfg.resolved_head_dim
+    H = cfg.cache_kv_heads
+    shape = (cfg.n_layers, batch, max_len, H * hd)
     kd = _kv_cache_dtype(cfg)
-    shape = (cfg.n_layers, batch, max_len, cfg.cache_kv_heads, hd)
-    cache = {
-        "k": jnp.zeros(shape, kd),
-        "v": jnp.zeros(shape, kd),
-    }
+    cache = {"k": jnp.zeros(shape, kd), "v": jnp.zeros(shape, kd)}
     if cfg.kv_cache_dtype == "int8":
-        cache["k_scale"] = jnp.zeros(shape[:-1] + (1,), F32)
-        cache["v_scale"] = jnp.zeros(shape[:-1] + (1,), F32)
+        cache["k_scale"] = jnp.zeros((cfg.n_layers, batch, H, max_len), F32)
+        cache["v_scale"] = jnp.zeros((cfg.n_layers, batch, H, max_len), F32)
     return cache
-
-
-def cache_pspec_tree(cfg: ModelConfig, cache):
-    """Logical specs for the cache (layers, batch, seq, kv_heads, hd)."""
-    spec = ("__layer", "batch", None, "model", None)
-    return jax.tree.map(lambda _: spec, cache,
-                        is_leaf=lambda x: not isinstance(x, dict))
 
 
 def _quantize_kv(x):
@@ -156,58 +151,49 @@ def _quantize_kv(x):
     return q, scale
 
 
-def _store_kv(cfg, ck, cv, ck_s, cv_s, k, v, pos):
-    """Scatter this step's (k, v) (B, S, H, D) into cache at positions pos (B,)."""
-    B = k.shape[0]
-    if cfg.kv_cache_dtype == "int8":
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-    else:
-        kq, vq, ks, vs = k, v, None, None
-
-    bidx = jnp.arange(B)[:, None]
-    S = k.shape[1]
-    tidx = pos[:, None] + jnp.arange(S)[None, :]
-    ck = ck.at[bidx, tidx].set(kq.astype(ck.dtype), mode="drop")
-    cv = cv.at[bidx, tidx].set(vq.astype(cv.dtype), mode="drop")
-    if ck_s is not None:
-        ck_s = ck_s.at[bidx, tidx].set(ks, mode="drop")
-        cv_s = cv_s.at[bidx, tidx].set(vs, mode="drop")
-    return ck, cv, ck_s, cv_s
+def _cache_entries(cfg: ModelConfig, k, v):
+    """(k, v) (..., T, H, hd) in the cache's layout: {"k", "v"} (..., T,
+    H*hd), and for int8 caches {"k_scale", "v_scale"} (..., H, T)."""
+    if cfg.kv_cache_dtype != "int8":
+        return {"k": k.reshape(k.shape[:-2] + (-1,)),
+                "v": v.reshape(v.shape[:-2] + (-1,))}
+    (kq, ks), (vq, vs) = _quantize_kv(k), _quantize_kv(v)
+    heads_first = lambda s: jnp.swapaxes(s[..., 0], -1, -2)
+    return {"k": kq.reshape(kq.shape[:-2] + (-1,)),
+            "v": vq.reshape(vq.shape[:-2] + (-1,)),
+            "k_scale": heads_first(ks), "v_scale": heads_first(vs)}
 
 
-def block_decode(p, x, cache_slices, pos, cfg: ModelConfig, *, n_groups: int = 1,
-                 window: Optional[int] = None):
-    """One decode step through one block. x: (B, 1, d); pos: (B,) current len."""
-    ck, cv, ck_s, cv_s = cache_slices
+def block_decode(p, x, cache, layer, pos, cfg: ModelConfig, *,
+                 n_groups: int = 1, window: Optional[int] = None):
+    """One decode step through one block. x: (B, 1, d); pos: (B,) rows
+    already cached. Reads layer `layer` of the stacked cache in place and
+    returns this step's (k, v) rows (B, H, hd) for the caller to write."""
     xn = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     hd = cfg.resolved_head_dim
-    B, T, _ = x.shape
+    B = x.shape[0]
 
     q = jnp.einsum("btd,dq->btq", xn, p["attn"]["wq"])
     k = jnp.einsum("btd,dk->btk", xn, p["attn"]["wk"])
     v = jnp.einsum("btd,dk->btk", xn, p["attn"]["wv"])
     if "bq" in p["attn"]:
         q, k, v = q + p["attn"]["bq"], k + p["attn"]["bk"], v + p["attn"]["bv"]
-    q = q.reshape(B, T, cfg.eff_q_heads, hd)
-    k = k.reshape(B, T, cfg.eff_kv_heads, hd)
-    v = v.reshape(B, T, cfg.eff_kv_heads, hd)
-    positions = pos[:, None] + jnp.arange(T)[None, :]
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
+    # a layout boundary: without it XLA meets the kernel's head-major q and
+    # k layouts by transposing wq and wk, a copy of both weights per layer
+    q, k, v = jax.lax.optimization_barrier((q, k, v))
+    q = L.rope(q.reshape(B, 1, cfg.eff_q_heads, hd), pos[:, None],
+               cfg.rope_theta)[:, 0]
+    k = L.rope(k.reshape(B, 1, cfg.eff_kv_heads, hd), pos[:, None],
+               cfg.rope_theta)[:, 0]
+    v = v.reshape(B, cfg.eff_kv_heads, hd)
     if cfg.kv_replication > 1:
-        k = jnp.repeat(k, cfg.kv_replication, axis=2)
-        v = jnp.repeat(v, cfg.kv_replication, axis=2)
+        k = jnp.repeat(k, cfg.kv_replication, axis=1)
+        v = jnp.repeat(v, cfg.kv_replication, axis=1)
 
-    ck, cv, ck_s, cv_s = _store_kv(cfg, ck, cv, ck_s, cv_s, k, v, pos)
-
-    kc, vc = ck, cv
-    kv_scale = None
-    if cfg.kv_cache_dtype == "int8":
-        kv_scale = ck_s  # k and v share the attend path; v scale applied below
-    valid = pos + T
-    out = _decode_attend(q, kc, vc, ck_s, cv_s, valid, cfg, window)
-    out = out.reshape(B, T, cfg.eff_q_heads * hd)
+    out = ops.decode_attention(q, k, v, cache["k"], cache["v"], layer, pos,
+                               cache.get("k_scale"), cache.get("v_scale"),
+                               window=window)
+    out = out.reshape(B, 1, cfg.eff_q_heads * hd)
     x = x + jnp.einsum("btq,qd->btd", out, p["attn"]["wo"])
 
     xn = L.rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -215,172 +201,49 @@ def block_decode(p, x, cache_slices, pos, cfg: ModelConfig, *, n_groups: int = 1
         y, _ = moe_ffn(p["moe"], xn, cfg, n_groups)
     else:
         y = L.swiglu(xn, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"])
-    return x + y, (ck, cv, ck_s, cv_s)
-
-
-def _decode_attend(q, ck, cv, ck_s, cv_s, valid, cfg, window):
-    """Attention of q (B, 1, Hq, hd) over the full cache buffer with a traced
-    validity bound (and dequantization for int8 caches)."""
-    return L.flash_attention_ref(
-        q, ck, cv, causal=False, window=window,
-        valid_len=valid, kv_scale=ck_s, v_scale=cv_s,
-        block_q=1, block_k=min(L.DECODE_BLOCK_K, ck.shape[1]))
-
-
-# direct-indexed decode: attend straight into the stacked (L,B,S,H,D) cache.
-# REFUTED as an XLA-level optimization (EXPERIMENTS.md §Perf qwen it3): the
-# traced-index scatter breaks while-carry aliasing and the cache gets copied
-# per layer. Kept selectable for the record; default off. The production
-# answer is the Pallas decode kernel (kernels/decode_attention.py), which
-# streams the cache exactly once by construction.
-DIRECT_CACHE_DECODE = False
-
-
-def _decode_attend_5d(q, ck_all, cv_all, cks_all, cvs_all, li, valid,
-                      block_k: int):
-    """Online-softmax decode attention slicing blocks from the 5D cache.
-
-    q: (B, Hc, R, hd) folded GQA; ck_all/cv_all: (L, B, S, Hc, hd);
-    scales (L, B, S, Hc, 1) or None; li: traced layer index; valid: (B,).
-    """
-    Lc, B, S, Hc, hd = ck_all.shape
-    R = q.shape[2]
-    block_k = min(block_k, S)
-    nk = S // block_k
-    scale = 1.0 / (hd ** 0.5)
-    F32 = jnp.float32
-
-    def slice5(a, j, width):
-        s = jax.lax.dynamic_slice(
-            a, (li, 0, j * block_k, 0, 0), (1, B, block_k, Hc, width))
-        return s[0]
-
-    def body(carry, j):
-        acc, m, l = carry
-        kb = slice5(ck_all, j, hd)
-        vb = slice5(cv_all, j, hd)
-        if cks_all is not None:
-            kb = kb.astype(F32) * slice5(cks_all, j, 1)
-            vb = vb.astype(F32) * slice5(cvs_all, j, 1)
-        s = jnp.einsum("bhrd,bkhd->bhrk", q.astype(F32), kb.astype(F32)) * scale
-        kpos = j * block_k + jnp.arange(block_k)
-        mask = kpos[None, :] < valid[:, None]              # (B, bk)
-        s = jnp.where(mask[:, None, None], s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, -1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None]) * mask[:, None, None]
-        l_new = l * alpha + jnp.sum(p, -1)
-        acc_new = acc * alpha[..., None] + \
-            jnp.einsum("bhrk,bkhd->bhrd", p, vb.astype(F32))
-        return (acc_new, m_new, l_new), None
-
-    acc0 = jnp.zeros((B, Hc, R, hd), F32)
-    m0 = jnp.full((B, Hc, R), -1e30, F32)
-    l0 = jnp.zeros((B, Hc, R), F32)
-    (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0), jnp.arange(nk))
-    out = acc / jnp.maximum(l, 1e-20)[..., None]
-    return out.astype(ck_all.dtype if ck_all.dtype != jnp.int8 else jnp.bfloat16)
-
-
-def block_decode_direct(p, x, caches, li, pos, cfg: ModelConfig, *,
-                        n_groups: int = 1):
-    """block_decode with in-place 5D cache writes + direct-indexed attention."""
-    ck_all, cv_all, cks_all, cvs_all = caches
-    xn = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    hd = cfg.resolved_head_dim
-    B, T, _ = x.shape
-
-    q = jnp.einsum("btd,dq->btq", xn, p["attn"]["wq"])
-    k = jnp.einsum("btd,dk->btk", xn, p["attn"]["wk"])
-    v = jnp.einsum("btd,dk->btk", xn, p["attn"]["wv"])
-    if "bq" in p["attn"]:
-        q, k, v = q + p["attn"]["bq"], k + p["attn"]["bk"], v + p["attn"]["bv"]
-    q = q.reshape(B, T, cfg.eff_q_heads, hd)
-    k = k.reshape(B, T, cfg.eff_kv_heads, hd)
-    v = v.reshape(B, T, cfg.eff_kv_heads, hd)
-    positions = pos[:, None] + jnp.arange(T)[None, :]
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
-    if cfg.kv_replication > 1:
-        k = jnp.repeat(k, cfg.kv_replication, axis=2)
-        v = jnp.repeat(v, cfg.kv_replication, axis=2)
-
-    bidx = jnp.arange(B)
-    if cfg.kv_cache_dtype == "int8":
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        cks_all = cks_all.at[li, bidx, pos].set(ks[:, 0], mode="drop")
-        cvs_all = cvs_all.at[li, bidx, pos].set(vs[:, 0], mode="drop")
-    else:
-        kq, vq = k, v
-    ck_all = ck_all.at[li, bidx, pos].set(kq[:, 0].astype(ck_all.dtype),
-                                          mode="drop")
-    cv_all = cv_all.at[li, bidx, pos].set(vq[:, 0].astype(cv_all.dtype),
-                                          mode="drop")
-
-    Hc = cfg.cache_kv_heads
-    R = cfg.eff_q_heads // Hc
-    qf = q.reshape(B, Hc, R, hd)
-    out = _decode_attend_5d(qf, ck_all, cv_all, cks_all, cvs_all, li,
-                            pos + T, block_k=L.DECODE_BLOCK_K)
-    out = out.reshape(B, T, cfg.eff_q_heads * hd).astype(x.dtype)
-    x = x + jnp.einsum("btq,qd->btd", out, p["attn"]["wo"])
-
-    xn = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        y, _ = moe_ffn(p["moe"], xn, cfg, n_groups)
-    else:
-        y = L.swiglu(xn, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"])
-    return x + y, (ck_all, cv_all, cks_all, cvs_all)
+    return x + y, (k, v)
 
 
 def lm_decode_step(params, cache, batch, cfg: ModelConfig, *, n_groups: int = 1,
                    window: Optional[int] = None):
     """One-token decode across the whole stack.
 
-    The cache rides in the scan *carry* and is updated in place per layer via
-    dynamic-update-slice, so XLA aliases one buffer through the loop (the
-    xs->ys formulation double-buffers the multi-TB cache)."""
+    The layer scan only reads the stacked cache: it is a loop-invariant
+    operand, and the attention kernel fetches layer `li`'s live blocks from
+    it in place. Each layer emits its new (k, v) row; one scatter after the
+    scan writes them all into the (donated) cache, one row per slot per
+    layer, dropping a row at or past max_len."""
     tokens, pos = batch["tokens"], batch["positions"]
     x = L.embed(params["embed"], tokens)
 
-    has_scale = "k_scale" in cache
-    zero = jnp.zeros((), F32)
-
     def body(carry, lp):
-        x_c, ck_all, cv_all, cks_all, cvs_all, li = carry
-        if DIRECT_CACHE_DECODE and window is None:
-            caches = (ck_all, cv_all,
-                      cks_all if has_scale else None,
-                      cvs_all if has_scale else None)
-            y, (ck_all, cv_all, cks2, cvs2) = block_decode_direct(
-                lp, x_c, caches, li, pos, cfg, n_groups=n_groups)
-            if has_scale:
-                cks_all, cvs_all = cks2, cvs2
-            return (y, ck_all, cv_all, cks_all, cvs_all, li + 1), None
-        take = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)
-        put = lambda a, s: jax.lax.dynamic_update_index_in_dim(a, s, li, 0)
-        slices = (take(ck_all), take(cv_all),
-                  take(cks_all) if has_scale else None,
-                  take(cvs_all) if has_scale else None)
-        y, (ck, cv, cks2, cvs2) = block_decode(lp, x_c, slices, pos, cfg,
-                                               n_groups=n_groups, window=window)
-        ck_all = put(ck_all, ck)
-        cv_all = put(cv_all, cv)
-        if has_scale:
-            cks_all = put(cks_all, cks2)
-            cvs_all = put(cvs_all, cvs2)
-        return (y, ck_all, cv_all, cks_all, cvs_all, li + 1), None
+        x_c, li = carry
+        y, rows = block_decode(lp, x_c, cache, li, pos, cfg,
+                               n_groups=n_groups, window=window)
+        return (y, li + 1), rows
 
-    carry0 = (x, cache["k"], cache["v"],
-              cache.get("k_scale", zero), cache.get("v_scale", zero),
-              jnp.zeros((), jnp.int32))
-    (x, nk, nv, nks, nvs, _), _ = jax.lax.scan(body, carry0, params["layers"])
+    (x, _), (k, v) = jax.lax.scan(body, (x, jnp.zeros((), jnp.int32)),
+                                  params["layers"])
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = L.unembed(params["embed"], x, cfg.vocab_size)
-    new_cache = {"k": nk, "v": nv}
-    if has_scale:
-        new_cache["k_scale"], new_cache["v_scale"] = nks, nvs
+    n_layers, B = k.shape[:2]
+    layer = jnp.repeat(jnp.arange(n_layers), B)          # one row per (l, b)
+    slot = jnp.tile(jnp.arange(B), n_layers)
+    row = jnp.tile(pos, n_layers)
+    new_cache = {}
+    for name, rows in _cache_entries(cfg, k[:, :, None], v[:, :, None]).items():
+        c = cache[name]
+        if name.endswith("_scale"):     # (L, B, H, 1) into (L, B, H, S)
+            # one element each: written as H-wide rows, XLA would lay the
+            # whole scale array out anew (H minor) and back every step
+            H = c.shape[2]
+            c = c.at[jnp.repeat(layer, H), jnp.repeat(slot, H),
+                     jnp.tile(jnp.arange(H), n_layers * B),
+                     jnp.repeat(row, H)].set(rows.reshape(-1), mode="drop")
+        else:                           # (L, B, 1, H*hd) into (L, B, S, H*hd)
+            c = c.at[layer, slot, row].set(
+                rows.reshape(n_layers * B, -1).astype(c.dtype), mode="drop")
+        new_cache[name] = c
     return logits, new_cache
 
 
@@ -395,9 +258,6 @@ def lm_prefill(params, batch, cfg: ModelConfig, *, n_groups: int = 1,
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     x = L.embed(params["embed"], tokens)
     x = _inject_frontend(params, batch, x, cfg)
-
-    hd = cfg.resolved_head_dim
-    int8 = cfg.kv_cache_dtype == "int8"
 
     def body(carry, lp):
         xc = carry
@@ -415,19 +275,9 @@ def lm_prefill(params, batch, cfg: ModelConfig, *, n_groups: int = 1,
         if cfg.kv_replication > 1:
             k = jnp.repeat(k, cfg.kv_replication, axis=2)
             v = jnp.repeat(v, cfg.kv_replication, axis=2)
-        if int8:
-            kq, ks = _quantize_kv(k)
-            vq, vs = _quantize_kv(v)
-            return xc, (kq, vq, ks, vs)
-        return xc, (k, v)
+        return xc, _cache_entries(cfg, k, v)
 
-    x, kvs = jax.lax.scan(body, x, params["layers"])
+    x, cache = jax.lax.scan(body, x, params["layers"])
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = L.unembed(params["embed"], x[:, -1:, :], cfg.vocab_size)
-    if int8:
-        k, v, ks, vs = kvs
-        cache = {"k": k, "v": v, "k_scale": ks, "v_scale": vs}
-    else:
-        k, v = kvs
-        cache = {"k": k, "v": v}
     return logits, cache

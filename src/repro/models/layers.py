@@ -4,7 +4,8 @@ Attention here is the *reference* (pure-jnp) path: a blocked online-softmax
 ("flash") implementation whose lowered memory is linear in sequence length,
 so the 512-device dry-run's memory_analysis reflects a production-quality
 attention. The Pallas kernels in repro.kernels (kernels/ops.py) are
-separate implementations of the same operations; no model calls them yet.
+separate implementations of the same operations; the decode step of the
+dense family (models/dense.py) calls the decode kernel.
 """
 from __future__ import annotations
 
@@ -22,12 +23,6 @@ NEG_INF = -1e30
 # training attention uses the blockwise custom-VJP backward by default
 # (set False to reproduce the paper-faithful §Perf baseline numbers)
 FLASH_VJP = True
-# int8-KV dequantization dtype for decode attention (bf16 halves the
-# dequantized-intermediate HBM traffic; scores still accumulate in fp32)
-DEQUANT_DTYPE = jnp.float32
-# decode attention kv block size (bigger blocks = fewer loop-boundary
-# buffers per step)
-DECODE_BLOCK_K = 1024
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -71,8 +66,6 @@ def flash_attention_ref(
     block_q: int = 512,
     block_k: int = 512,
     valid_len: Optional[jax.Array] = None,  # (B,) traced per-seq kv validity bound
-    kv_scale: Optional[jax.Array] = None,   # (B, Tk, Hkv, 1) int8 k dequant scale
-    v_scale: Optional[jax.Array] = None,    # (B, Tk, Hkv, 1) int8 v dequant scale
 ) -> jax.Array:
     """Blocked online-softmax attention with GQA folding.
 
@@ -92,7 +85,7 @@ def flash_attention_ref(
     nk = -(-Tk // block_k)
     assert Tq % block_q == 0 and Tk % block_k == 0, (Tq, Tk, block_q, block_k)
 
-    if FLASH_VJP and valid_len is None and kv_scale is None and v_scale is None:
+    if FLASH_VJP and valid_len is None:
         # training path: blockwise custom-VJP (flash backward) -- saves only
         # (q,k,v,o,lse), recomputes p per tile (EXPERIMENTS.md §Perf it1)
         from repro.models.flash_vjp import flash_attention_vjp
@@ -102,8 +95,6 @@ def flash_attention_ref(
     qr = q.reshape(B, nq, block_q, Hkv, R, Dh)
     kr = k.reshape(B, nk, block_k, Hkv, Dh)
     vr = v.reshape(B, nk, block_k, Hkv, Dh)
-    ksr = kv_scale.reshape(B, nk, block_k, Hkv, 1) if kv_scale is not None else None
-    vsr = v_scale.reshape(B, nk, block_k, Hkv, 1) if v_scale is not None else None
 
     out_blocks = []
     for i in range(nq):
@@ -124,12 +115,6 @@ def flash_attention_ref(
             acc, m, l = carry
             kb = jax.lax.dynamic_index_in_dim(kr, j, axis=1, keepdims=False)
             vb = jax.lax.dynamic_index_in_dim(vr, j, axis=1, keepdims=False)
-            if ksr is not None:
-                sb = jax.lax.dynamic_index_in_dim(ksr, j, axis=1, keepdims=False)
-                kb = (kb.astype(F32) * sb).astype(DEQUANT_DTYPE)
-            if vsr is not None:
-                sb = jax.lax.dynamic_index_in_dim(vsr, j, axis=1, keepdims=False)
-                vb = (vb.astype(F32) * sb).astype(DEQUANT_DTYPE)
             s = jnp.einsum("bqhrd,bkhd->bhrqk", q_blk.astype(kb.dtype),
                            kb, preferred_element_type=F32) * scale
             qpos = q_start + jnp.arange(block_q)
@@ -222,7 +207,6 @@ def init_attention(key, d_model, n_heads, n_kv_heads, head_dim, qkv_bias, dtype,
 def attention(
     p, x, positions, cfg, *,
     kv: Optional[Tuple[jax.Array, jax.Array]] = None,   # cached (k, v)
-    kv_scale: Optional[jax.Array] = None,
     causal: bool = True,
     window: Optional[int] = None,
     q_offset: int = 0,
@@ -263,7 +247,7 @@ def attention(
             k, v = kv
             out = flash_attention_ref(q, k, v, causal=False, window=window,
                                       q_offset=q_offset, block_q=block_q,
-                                      block_k=block_k, kv_scale=kv_scale)
+                                      block_k=block_k)
         else:
             out = flash_attention_ref(q, k, v, causal=causal, window=window,
                                       q_offset=q_offset, block_q=block_q,

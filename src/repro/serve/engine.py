@@ -68,6 +68,10 @@ class ServeEngine:
         with jax.default_device(self.device):   # built in place, no hop
             self.cache = self._put(model.init_cache(batch_slots, max_len))
             self.positions = self._put(jnp.zeros((batch_slots,), jnp.int32))
+            # 1 for an answering slot: only those advance, so an empty slot
+            # stays at position 0, where the decode step reads none of its
+            # cache
+            self.live = self._put(jnp.zeros((batch_slots,), jnp.int32))
             self.tokens = self._put(jnp.zeros((batch_slots, 1), jnp.int32))
         self.slot_req: List[Optional[Request]] = [None] * batch_slots
         self.queue: "collections.deque[Request]" = collections.deque()
@@ -150,6 +154,7 @@ class ServeEngine:
         req.output.append(next_tok)
         self._scatter_cache(pcache, slot, len(req.prompt))
         self.positions = self.positions.at[slot].set(len(req.prompt))
+        self.live = self.live.at[slot].set(1)
         self.tokens = self.tokens.at[slot, 0].set(next_tok)
         self.slot_req[slot] = req
         self.stats["prefills"] += 1
@@ -159,8 +164,9 @@ class ServeEngine:
         def per_leaf(big, small):
             if big.ndim < 2 or big.shape[1] != self.B:
                 return big
-            pad_width = [(0, 0)] * small.ndim
-            pad_width[2] = (0, big.shape[2] - small.shape[2])
+            pad_width = [(0, 0) if i == 1 else (0, b - s)   # up to max_len
+                         for i, (b, s) in enumerate(zip(big.shape,
+                                                        small.shape))]
             small_p = jnp.pad(small, pad_width)
             return big.at[:, slot].set(small_p[:, 0].astype(big.dtype))
         self.cache = jax.tree.map(per_leaf, self.cache, pcache)
@@ -182,7 +188,7 @@ class ServeEngine:
         with SPANS.span("engine.decode"):
             logits, self.cache = self._decode(self.params, self.cache, batch)
         next_tokens = self._read(jnp.argmax(logits[:, -1, :], axis=-1))
-        self.positions = self.positions + 1
+        self.positions = self.positions + self.live
         self.stats["ticks"] += 1
         for s in active:
             req = self.slot_req[s]
@@ -194,6 +200,8 @@ class ServeEngine:
                     or int(self._read(self.positions[s])) >= self.max_len - 1):
                 req.done = True
                 self.slot_req[s] = None
+                self.positions = self.positions.at[s].set(0)
+                self.live = self.live.at[s].set(0)
                 self._completed.append(req)
                 self.stats["completed"] += 1
         self.tokens = self._put(next_tokens.astype(np.int32)[:, None])

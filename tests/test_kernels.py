@@ -87,6 +87,26 @@ def test_flash_attention_property(T, R, D):
 
 # ------------------------------------------------------------- decode attention
 
+def _stacked_cache(key, L, B, S, Hkv, D, dtype):
+    """A random stacked cache (L,B,S,Hkv*D) as (k, v, k_scale, v_scale,
+    k_float, v_float): int8 comes with its per-(token, head) scales
+    (L,B,Hkv,S), and the float32 values it was made from."""
+    ks = jax.random.split(key, 2)
+    kf = jax.random.normal(ks[0], (L, B, S, Hkv, D), jnp.float32)
+    vf = jax.random.normal(ks[1], (L, B, S, Hkv, D), jnp.float32)
+    flat = lambda x: x.reshape(L, B, S, Hkv * D)
+    if dtype != jnp.int8:
+        return (flat(kf).astype(dtype), flat(vf).astype(dtype), None, None,
+                flat(kf), flat(vf))
+    out = []
+    for x in (kf, vf):
+        sc = jnp.max(jnp.abs(x), -1) / 127.0
+        q8 = jnp.round(x / sc[..., None]).astype(jnp.int8)
+        out.append((flat(q8), sc.transpose(0, 1, 3, 2)))
+    (k8, ksc), (v8, vsc) = out
+    return k8, v8, ksc, vsc, flat(kf), flat(vf)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("B,Hq,Hkv,S,D", [
     (2, 4, 2, 128, 32),
@@ -94,41 +114,80 @@ def test_flash_attention_property(T, R, D):
     (3, 2, 1, 64, 16),
 ])
 def test_decode_attention_sweep(B, Hq, Hkv, S, D, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(S + Hq), 3)
+    ks = jax.random.split(jax.random.PRNGKey(S + Hq), 4)
     q = _rand(ks[0], (B, Hq, D), dtype)
-    k = _rand(ks[1], (B, Hkv, S, D), dtype)
-    v = _rand(ks[2], (B, Hkv, S, D), dtype)
-    vl = jnp.arange(1, B + 1) * (S // (B + 1)) + 1
-    out = ops.decode_attention(q, k, v, vl, block_k=S // 2)
-    want = ref.attention_ref(q[:, :, None], k, v, causal=False,
-                             valid_len=vl)[:, :, 0]
+    kn = _rand(ks[1], (B, Hkv, D), dtype)
+    vn = _rand(ks[2], (B, Hkv, D), dtype)
+    k, v, *_ = _stacked_cache(ks[3], 2, B, S, Hkv, D, dtype)
+    pos = jnp.arange(1, B + 1) * (S // (B + 1)) + 1
+    out = ops.decode_attention(q, kn, vn, k, v, 1, pos, block_k=S // 2)
+    want = ref.decode_attention_ref(q, kn, vn, k, v, 1, pos)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
 
 
 def test_decode_attention_int8_cache():
     B, Hq, Hkv, S, D = 2, 4, 2, 128, 32
-    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
     q = _rand(ks[0], (B, Hq, D), jnp.float32)
-    kf = _rand(ks[1], (B, Hkv, S, D), jnp.float32)
-    vf = _rand(ks[2], (B, Hkv, S, D), jnp.float32)
-    # quantize per (head, token)
-    ksc = jnp.max(jnp.abs(kf), -1, keepdims=True) / 127.0
-    vsc = jnp.max(jnp.abs(vf), -1, keepdims=True) / 127.0
-    k8 = jnp.round(kf / ksc).astype(jnp.int8)
-    v8 = jnp.round(vf / vsc).astype(jnp.int8)
-    vl = jnp.array([64, 128])
-    out = ops.decode_attention(q, k8, v8, vl, k_scale=ksc, v_scale=vsc,
+    kn = _rand(ks[1], (B, Hkv, D), jnp.float32)
+    vn = _rand(ks[2], (B, Hkv, D), jnp.float32)
+    k8, v8, ksc, vsc, kf, vf = _stacked_cache(ks[3], 1, B, S, Hkv, D,
+                                              jnp.int8)
+    pos = jnp.array([64, 127])
+    out = ops.decode_attention(q, kn, vn, k8, v8, 0, pos, ksc, vsc,
                                block_k=64)
-    want = ref.attention_ref(q[:, :, None], k8, v8, causal=False,
-                             valid_len=vl, kv_scale=ksc, v_scale=vsc)[:, :, 0]
+    want = ref.decode_attention_ref(q, kn, vn, k8, v8, 0, pos,
+                                    k_scale=ksc, v_scale=vsc)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
-    # and the dequantized result is close to the fp32 attention
-    exact = ref.attention_ref(q[:, :, None], kf, vf, causal=False,
-                              valid_len=vl)[:, :, 0]
+    # and the dequantized result is close to the fp32 attention over the
+    # values the cache was quantized from
+    exact = ref.decode_attention_ref(q, kn, vn, kf, vf, 0, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exact),
                                atol=0.05, rtol=0.05)
+
+
+@pytest.mark.parametrize("window", [None, 24])
+@pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8])
+@pytest.mark.parametrize("Hq,Hkv", [(8, 2), (4, 4)], ids=["gqa4", "mha"])
+def test_decode_attention_reads_stacked_cache(Hq, Hkv, cache_dtype, window):
+    """The kernel on the stacked (L,B,S,Hkv*D) cache at layer 2 equals
+    `flash_attention_ref` over each slot's cached rows plus the step's own
+    row: pos at 0, at a block edge, inside a block, one below max_len and
+    at max_len (the new row is not cached, only attended)."""
+    from repro.models.layers import flash_attention_ref
+    L, S, D, bk = 3, 64, 32, 16
+    pos = jnp.array([0, bk, 2 * bk + 5, S - 1, S], jnp.int32)
+    B = pos.shape[0]
+    ks = jax.random.split(jax.random.PRNGKey(Hq * 7 + Hkv), 4)
+    q = _rand(ks[0], (B, Hq, D), jnp.bfloat16)
+    kn = _rand(ks[1], (B, Hkv, D), jnp.bfloat16)
+    vn = _rand(ks[2], (B, Hkv, D), jnp.bfloat16)
+    k, v, ksc, vsc, *_ = _stacked_cache(ks[3], L, B, S, Hkv, D,
+                                        cache_dtype)
+    out = ops.decode_attention(q, kn, vn, k, v, 2, pos, ksc, vsc,
+                               window=window, block_k=bk)
+
+    def rows(cache, scale, b):          # layer 2, slot b, as float32
+        x = cache[2, b].reshape(S, Hkv, D).astype(jnp.float32)
+        return x if scale is None else x * scale[2, b].T[..., None]
+
+    for b in range(B):
+        p = int(pos[b])
+        lo = 0 if window is None else max(0, p - window + 1)
+        hi = min(p, S)
+        kr = jnp.concatenate([rows(k, ksc, b)[lo:hi],
+                              kn[b, None].astype(jnp.float32)])
+        vr = jnp.concatenate([rows(v, vsc, b)[lo:hi],
+                              vn[b, None].astype(jnp.float32)])
+        want = flash_attention_ref(
+            q[b, None, None].astype(jnp.float32), kr[None], vr[None],
+            causal=False, block_q=1, block_k=kr.shape[0],
+            valid_len=jnp.array([kr.shape[0]]))
+        np.testing.assert_allclose(np.asarray(out[b], np.float32),
+                                   np.asarray(want[0, 0]),
+                                   atol=2e-2, rtol=2e-2)
 
 
 # ------------------------------------------------------------- grouped matmul

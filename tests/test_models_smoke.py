@@ -100,6 +100,42 @@ def test_decode_matches_full_forward(arch):
         assert bool(jnp.all(jnp.isfinite(dec_logits.astype(jnp.float32))))
 
 
+def test_windowed_decode_matches_windowed_forward():
+    """With a sliding window, decode steps through the cache attend to the
+    keys the windowed full forward attends to at the same positions: the
+    window's lower edge falls inside the first of two 512-row key blocks,
+    so the decode kernel masks part of a block and skips none it needs."""
+    from repro.models import dense as D
+    from repro.models import layers as L
+    cfg = get_config("llama3-8b", smoke=True).replace(param_dtype="float32")
+    params = build_model(cfg, n_groups=1).init_params(jax.random.PRNGKey(5))
+    # the forward runs over S tokens (its blocks tile them); being causal,
+    # its logits at T..T+n_dec-1 see only the tokens up to each
+    B, T, n_dec, S, W = 2, 512, 3, 1024, 24
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (B, S), 0,
+                                cfg.vocab_size, jnp.int32)
+
+    def forward(window):
+        pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        x, _ = D.backbone_fwd(params, L.embed(params["embed"], tokens), pos,
+                              cfg, window=window, remat=False)
+        return L.unembed(params["embed"], x, cfg.vocab_size)[:, T:T + n_dec]
+
+    want, unwindowed = forward(W), forward(None)
+    _, cache = D.lm_prefill(params, {"tokens": tokens[:, :T]}, cfg, window=W)
+    cache = jax.tree.map(
+        lambda c: jnp.pad(c, [(0, 0), (0, 0), (0, S - T), (0, 0)]), cache)
+    step = jax.jit(lambda c, b: D.lm_decode_step(params, c, b, cfg, window=W))
+    for i in range(n_dec):
+        batch = {"tokens": tokens[:, T + i:T + i + 1],
+                 "positions": jnp.full((B,), T + i, jnp.int32)}
+        logits, cache = step(cache, batch)
+        got = logits[:, 0].astype(jnp.float32)
+        assert jnp.allclose(got, want[:, i], atol=1e-3, rtol=1e-3), i
+        # the window changes the answer, so the match is not vacuous
+        assert not jnp.allclose(got, unwindowed[:, i], atol=1e-3, rtol=1e-3)
+
+
 def test_zamba2_decode_consistency_with_prefill_path():
     """Hybrid arch: stepwise decode from scratch equals the parallel
     (chunked-SSD) forward at the final position."""
