@@ -79,9 +79,10 @@ Cells chosen per spec: worst roofline cell (arctic-480b x train_4k:
 over-memory + biggest model), most representative dense training
 (llama3-8b x train_4k), and the serving shape Syndeo fleets run at scale
 (qwen1.5-32b x decode_32k). Baseline = paper-faithful implementation
-(tag `baseline`, flag `flash_vjp=False`; its `direct_cache=False` flag
-went with the direct path, below); optimized
-variants are cumulative and live under their own tags. Stopping rule: three
+(tag `baseline`; its `flash_vjp=False` and `direct_cache=False` flags
+went with the paths they selected: every run now takes the custom-VJP
+attention); optimized variants are cumulative and live under their own
+tags. Stopping rule: three
 consecutive <5% changes on the dominant term, or term moved below the next
 one.
 

@@ -19,15 +19,12 @@ sys.path.insert(0, "src")
 
 ITERATIONS = [
     # (arch, shape, tag, overrides)
-    ("llama3-8b", "train_4k", "it1_flashvjp", {"flash_vjp": True}),
-    ("llama3-8b", "train_4k", "it2_sp",
-     {"flash_vjp": True, "rules": {"seq": ("model",)}}),
-    ("arctic-480b", "train_4k", "it1_flashvjp", {"flash_vjp": True}),
-    ("arctic-480b", "train_4k", "it2_sp",
-     {"flash_vjp": True, "rules": {"seq": ("model",)}}),
+    ("llama3-8b", "train_4k", "it1_flashvjp", {}),
+    ("llama3-8b", "train_4k", "it2_sp", {"rules": {"seq": ("model",)}}),
+    ("arctic-480b", "train_4k", "it1_flashvjp", {}),
+    ("arctic-480b", "train_4k", "it2_sp", {"rules": {"seq": ("model",)}}),
     ("arctic-480b", "train_4k", "it3_bf16accum",
-     {"flash_vjp": True, "rules": {"seq": ("model",)},
-      "accum_dtype": "bfloat16"}),
+     {"rules": {"seq": ("model",)}, "accum_dtype": "bfloat16"}),
 ]
 
 

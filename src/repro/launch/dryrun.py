@@ -161,8 +161,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                overrides: Optional[Dict[str, Any]] = None):
     """Lower + compile one cell; returns (lowered, compiled, meta)."""
     overrides = overrides or {}
-    import repro.models.layers as _L
-    _L.FLASH_VJP = overrides.get("flash_vjp", True)
     cfg = get_config(arch)
     for k, v in overrides.get("cfg", {}).items():
         cfg = cfg.replace(**{k: v})

@@ -1,11 +1,13 @@
 """Shared model building blocks, pure JAX.
 
-Attention here is the *reference* (pure-jnp) path: a blocked online-softmax
-("flash") implementation whose lowered memory is linear in sequence length,
-so the 512-device dry-run's memory_analysis reflects a production-quality
-attention. The Pallas kernels in repro.kernels (kernels/ops.py) are
-separate implementations of the same operations; the decode step of the
-dense family (models/dense.py) calls the decode kernel.
+Attention here is pure jnp and blocked ("flash"), so its lowered memory is
+linear in sequence length and the 512-device dry-run's memory_analysis
+reflects a production-quality attention. Prefill and training take the
+blockwise custom-VJP path (models/flash_vjp.py); the online-softmax loop
+in `flash_attention_ref` serves the callers that bound each sequence's
+keys (`valid_len`). The Pallas kernels in repro.kernels (kernels/ops.py)
+are separate implementations of the same operations; the decode step of
+the dense family (models/dense.py) calls the decode kernel.
 """
 from __future__ import annotations
 
@@ -19,10 +21,6 @@ from repro.sharding.axes import constrain
 
 F32 = jnp.float32
 NEG_INF = -1e30
-
-# training attention uses the blockwise custom-VJP backward by default
-# (set False to reproduce the paper-faithful §Perf baseline numbers)
-FLASH_VJP = True
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -69,7 +67,10 @@ def flash_attention_ref(
 ) -> jax.Array:
     """Blocked online-softmax attention with GQA folding.
 
-    The outer loop over q-blocks is a static python loop so that each q-block
+    Without `valid_len` this is the blockwise custom-VJP attention
+    (models/flash_vjp.py), whose backward saves only (q, k, v, o, lse)
+    and recomputes p per tile. With it, the loop below runs: its outer
+    loop over q-blocks is a static python loop so that each q-block
     scans only the kv-blocks its causal/window footprint needs -- the lowered
     FLOPs match a production flash kernel (no masked-out waste beyond block
     granularity).
@@ -85,9 +86,7 @@ def flash_attention_ref(
     nk = -(-Tk // block_k)
     assert Tq % block_q == 0 and Tk % block_k == 0, (Tq, Tk, block_q, block_k)
 
-    if FLASH_VJP and valid_len is None:
-        # training path: blockwise custom-VJP (flash backward) -- saves only
-        # (q,k,v,o,lse), recomputes p per tile (EXPERIMENTS.md §Perf it1)
+    if valid_len is None:
         from repro.models.flash_vjp import flash_attention_vjp
         return flash_attention_vjp(q, k, v, causal, window, q_offset,
                                    block_q, block_k)
@@ -124,10 +123,7 @@ def flash_attention_ref(
                 mask &= qpos[:, None] >= kpos[None, :]
             if window is not None:
                 mask &= kpos[None, :] > qpos[:, None] - window
-            if valid_len is not None:
-                maskb = mask[None] & (kpos[None, None, :] < valid_len[:, None, None])
-            else:
-                maskb = mask[None]
+            maskb = mask[None] & (kpos[None, None, :] < valid_len[:, None, None])
             s = jnp.where(maskb[:, None, None], s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             alpha = jnp.exp(m - m_new)

@@ -35,11 +35,13 @@ Fault handling:
 
 Replica handles are duck-typed: anything with the engine surface
 (``add_request`` / ``tick`` / ``pop_completed`` / ``run_until_drained`` /
-``free_slots`` / ``queue_len`` / ``outstanding_tokens``) serves -- a
-local ``StubEngine``/``ServeEngine``, the simulator's virtual replicas,
-or `ActorReplicaHandle`, which adapts the same surface over the wire
-protocol's ``actor_call`` ops to a `ReplicaActor` hosted by a remote
-worker.
+``free_slots`` / ``queue_len`` / ``outstanding_tokens``) serves. The
+engine's `SlotScheduler` defines that surface once, so a local
+``ServeEngine`` and the model-free ``StubEngine`` share it by
+construction and differ only in their tokens; the simulator's virtual
+replicas forward it to a ``StubEngine``, and `ActorReplicaHandle`
+adapts it over the wire protocol's ``actor_call`` ops to a
+`ReplicaActor` hosted by a remote worker.
 
 `stats_sink`, called after every tick with a snapshot
 (requests/shed/completed/p99_ms/replicas), is how the head's `metrics`

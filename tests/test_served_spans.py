@@ -1,6 +1,7 @@
 """Spans of the served path, recorded where the work happens: the parts
 of one actor round trip (client, head, worker), and the engine's queue,
 admissions, host syncs and compiles."""
+import threading
 import time
 
 import jax
@@ -153,3 +154,67 @@ def test_decode_step_program_is_named_after_it(smoke_model):
     batch = {"tokens": engine.tokens, "positions": engine.positions}
     text = engine._decode.lower(engine.params, engine.cache, batch).as_text()
     assert text.startswith("module @jit__decode_step ")
+
+
+# (id, prompt length, budget, tick it arrives at), max_len 32: 401 and 405
+# fill max_len (30 + 2, 20 + 12) before their budgets, the rest end on
+# their budgets
+TRACES = {
+    "staggered": [(401, 30, 8, 0), (402, 4, 3, 0), (403, 6, 5, 1),
+                  (404, 3, 2, 2), (405, 20, 30, 3), (406, 5, 4, 4)],
+    "burst": [(401, 30, 8, 0), (402, 4, 3, 0), (403, 6, 5, 0),
+              (404, 3, 2, 0), (405, 20, 30, 0), (406, 5, 4, 0)],
+}
+SCHEDULE_SPANS = ("engine.queue", "engine.admit", "engine.tick")
+
+
+def _schedule(engine, trace):
+    """Feed `trace` to `engine` tick by tick, until it drains. Returns,
+    per tick, what the router sees of the engine and what it handed back,
+    and the engine's queue, admission and tick spans (less `syncs`, the
+    host reads, which only the model-backed engine makes)."""
+    reqs = {i: Request(id=i, prompt=[1 + (i + j) % 7 for j in range(n)],
+                       max_new_tokens=budget) for i, n, budget, _ in trace}
+    me, t0 = threading.get_ident(), time.perf_counter()
+    rows, tick = [], 0
+    while tick <= max(at for *_, at in trace) or engine.queue_len \
+            or any(r is not None for r in engine.slot_req):
+        for i, _, _, at in trace:
+            if at == tick:
+                engine.add_request(reqs[i])
+        active = engine.tick()
+        rows.append((active, [r and r.id for r in engine.slot_req],
+                     engine.free_slots, engine.queue_len,
+                     engine.outstanding_tokens,
+                     sorted((r.id, len(r.output))
+                            for r in engine.pop_completed())))
+        tick += 1
+    assert SPANS.horizon < t0
+    spans = [s for s in _since(t0)
+             if s.thread == me and s.name in SCHEDULE_SPANS]
+    ticks = [s.id for s in spans if s.name == "engine.tick"]
+    return rows, [(s.name, ticks.index(s.parent) if s.parent in ticks
+                   else None,
+                   {k: v for k, v in s.attrs.items() if k != "syncs"})
+                  for s in spans]
+
+
+@pytest.mark.parametrize("slots", [1, 2, 3])
+@pytest.mark.parametrize("trace", sorted(TRACES))
+def test_stub_and_served_engines_schedule_alike(smoke_model, trace, slots):
+    model, params = smoke_model
+    served = _schedule(
+        ServeEngine(model, params, batch_slots=slots, max_len=32),
+        TRACES[trace])
+    stub = _schedule(StubEngine(batch_slots=slots, max_len=32),
+                     TRACES[trace])
+    assert stub[0] == served[0]
+    assert stub[1] == served[1]
+    rows, spans = stub
+    lengths = dict(done for row in rows for done in row[-1])
+    budgets = {i: budget for i, _, budget, _ in TRACES[trace]}
+    assert lengths == {**budgets, 401: 2, 405: 12}
+    assert [a["req"] for name, _, a in spans if name == "engine.admit"] \
+        == [a["req"] for name, _, a in spans if name == "engine.queue"]
+    assert [a["active"] for name, _, a in spans if name == "engine.tick"] \
+        == [row[0] for row in rows]
