@@ -217,15 +217,22 @@ def warm(engine: ServeEngine, lens: Sequence[int]) -> float:
 
 
 class LogitTap:
-    """Keeps the logits the engine's own compiled programs produce: each
-    prompt's prefill logits (by prompt) and each request's first decode
-    step (by request id). The served tokens alone cannot be held to the
-    reference: with random weights near-ties make argmax fragile."""
+    """Keeps each prompt's prefill logits (by prompt), from the engine's
+    own compiled prefill, and each request's first decode-step logits (by
+    request id), from the model's decode step on the inputs of the
+    engine's step, run just before it (the engine's step returns only
+    the next tokens and positions). The served tokens alone cannot be
+    held to the reference: with random weights near-ties make argmax
+    fragile."""
 
     def __init__(self, engine: ServeEngine):
         self.prefill: Dict[Tuple[int, ...], np.ndarray] = {}
         self.decode: Dict[int, np.ndarray] = {}
         prefill, decode = engine._prefill_one, engine._decode
+        step_logits = jax.jit(lambda params, cache, batch: engine.model
+                              .decode_step(params, cache, {
+                                  "tokens": batch["tokens"],
+                                  "positions": batch["positions"]})[0])
 
         def prefill_tap(params, tokens):
             logits, cache = prefill(params, tokens)
@@ -236,10 +243,11 @@ class LogitTap:
         def decode_tap(params, cache, batch):
             fresh = {s: r.id for s, r in enumerate(engine.slot_req)
                      if r is not None and len(r.output) == 1}
-            logits, cache = decode(params, cache, batch)
-            for s, rid in fresh.items():
-                self.decode[rid] = np.asarray(logits[s, -1], np.float32)
-            return logits, cache
+            if fresh:               # before the step donates the cache
+                logits = step_logits(params, cache, batch)
+                for s, rid in fresh.items():
+                    self.decode[rid] = np.asarray(logits[s, -1], np.float32)
+            return decode(params, cache, batch)
 
         engine._prefill_one, engine._decode = prefill_tap, decode_tap
 

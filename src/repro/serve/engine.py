@@ -5,9 +5,11 @@ compiled decode step, no re-compilation as requests come and go):
   * new requests are prefilled one at a time, at their own prompt length
     (one prefill program is compiled per length), and their cache
     scattered into a free slot,
-  * every engine tick decodes all active slots in one batched step,
-  * finished slots (EOS, budget or max_len) are freed and refilled from
-    the queue.
+  * every engine tick reads the batched step the previous tick launched,
+    frees the slots that ended (EOS, budget or max_len), refills them
+    from the queue, and launches the next step over every active slot
+    before it returns, so the chip decodes while the host hands the
+    tokens back and the next tick call comes in.
 
 `SlotScheduler` holds that loop -- queue, slots, admission, completion,
 the `engine.*` spans -- once; `ServeEngine` (a model) and `StubEngine` (a
@@ -61,10 +63,12 @@ class Request:
 
 
 class SlotScheduler:
-    """B decode slots fed from a FIFO queue. Each tick admits queued
-    requests into empty slots, takes one step over every active slot, and
-    frees a slot whose request ended: on its EOS, on its budget of
-    `max_new_tokens`, or when prompt and output fill `max_len`."""
+    """B decode slots fed from a FIFO queue. Each tick reads the tokens of
+    the step the previous tick launched, frees a slot whose request ended
+    (on its EOS, on its budget of `max_new_tokens`, or when prompt and
+    output fill `max_len`), admits queued requests into empty slots, and
+    launches the next step over every active slot without waiting for
+    it."""
 
     def __init__(self, batch_slots: int, max_len: int):
         self.B = batch_slots
@@ -73,6 +77,7 @@ class SlotScheduler:
         self.queue: "collections.deque[Request]" = collections.deque()
         self._queued_at: Dict[int, float] = {}    # id(req) -> add_request
         self._completed: List[Request] = []
+        self._stepping: List[int] = []    # the slots of the step in flight
         self.stats = {"ticks": 0, "prefills": 0, "decoded_tokens": 0,
                       "completed": 0}
 
@@ -82,21 +87,16 @@ class SlotScheduler:
         """Prefill `req` into the empty `slot`; return its first token."""
         raise NotImplementedError
 
-    def _step(self) -> Sequence[Optional[int]]:
-        """One step over the active slots; the next token of each slot."""
+    def _launch(self) -> None:
+        """Start one step over the active slots; do not wait for it."""
         raise NotImplementedError
 
-    def _at_max_len(self, slot: int, req: Request) -> bool:
-        """Whether `req`, in `slot`, has filled `max_len`; asked only of a
-        request that its token and budget have not ended."""
+    def _collect(self) -> Sequence[Optional[int]]:
+        """The next token of each slot, from the step `_launch` started."""
         raise NotImplementedError
 
     def _free(self, slot: int) -> None:
         """Release what the engine holds for the ended request in `slot`."""
-
-    def _feed(self, tokens: Sequence[Optional[int]]) -> None:
-        """Take the step's tokens as the next step's input, once every
-        ended slot is freed."""
 
     # -- request management ------------------------------------------------------
 
@@ -146,32 +146,40 @@ class SlotScheduler:
     # -- the decode tick -----------------------------------------------------------
 
     def tick(self) -> int:
-        """One engine iteration; returns number of active slots decoded."""
-        with SPANS.span("engine.tick", active=0, syncs=0) as span:
+        """One engine iteration; returns the number of slots it answered:
+        those of the step it read and those of the step it launched.
+        `ahead` is 1 where it read a step the previous tick launched."""
+        with SPANS.span("engine.tick", active=0, syncs=0,
+                        ahead=int(bool(self._stepping))) as span:
             span.attrs["active"] = n = self._tick()
         return n
 
     def _tick(self) -> int:
+        stepped = self._stepping
+        if stepped:
+            with SPANS.span("engine.step_wait"):
+                next_tokens = self._collect()
+            for s in stepped:
+                req = self.slot_req[s]
+                tok = int(next_tokens[s])
+                req.output.append(tok)
+                self.stats["decoded_tokens"] += 1
+                if (tok == req.eos_id or len(req.output) >= req.max_new_tokens
+                        or len(req.prompt) + len(req.output) >= self.max_len):
+                    req.done = True
+                    self.slot_req[s] = None
+                    self._free(s)
+                    self._completed.append(req)
+                    self.stats["completed"] += 1
         self._fill_free_slots()
-        active = [s for s in range(self.B) if self.slot_req[s] is not None]
-        if not active:
-            return 0
-        next_tokens = self._step()
-        self.stats["ticks"] += 1
-        for s in active:
-            req = self.slot_req[s]
-            tok = int(next_tokens[s])
-            req.output.append(tok)
-            self.stats["decoded_tokens"] += 1
-            if (tok == req.eos_id or len(req.output) >= req.max_new_tokens
-                    or self._at_max_len(s, req)):
-                req.done = True
-                self.slot_req[s] = None
-                self._free(s)
-                self._completed.append(req)
-                self.stats["completed"] += 1
-        self._feed(next_tokens)
-        return len(active)
+        self._stepping = [s for s in range(self.B)
+                          if self.slot_req[s] is not None]
+        if self._stepping:
+            self._launch()
+        answered = len(set(stepped) | set(self._stepping))
+        if answered:
+            self.stats["ticks"] += 1
+        return answered
 
     def pop_completed(self) -> List[Request]:
         """Requests finished since the last pop (the router's per-tick
@@ -210,8 +218,14 @@ class ServeEngine(SlotScheduler):
 
     def _decode_step(self, params, cache, batch):
         """The batched decode step, jitted under this name (a profile
-        names the program `jit__decode_step`)."""
-        return self.model.decode_step(params, cache, batch)
+        names the program `jit__decode_step`). It returns the next step's
+        inputs, so they never leave the device: each slot's greedy token,
+        and its position advanced where it is `live`."""
+        logits, cache = self.model.decode_step(
+            params, cache,
+            {"tokens": batch["tokens"], "positions": batch["positions"]})
+        tokens = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+        return (tokens[:, None], batch["positions"] + batch["live"]), cache
 
     def _prefill_impl(self, params, tokens):
         return self.model.prefill(params, {"tokens": tokens})
@@ -253,23 +267,19 @@ class ServeEngine(SlotScheduler):
             return big.at[:, slot].set(small_p[:, 0].astype(big.dtype))
         self.cache = jax.tree.map(per_leaf, self.cache, pcache)
 
-    def _step(self) -> np.ndarray:
-        batch = {"tokens": self.tokens, "positions": self.positions}
+    def _launch(self) -> None:
+        batch = {"tokens": self.tokens, "positions": self.positions,
+                 "live": self.live}
         with SPANS.span("engine.decode"):
-            logits, self.cache = self._decode(self.params, self.cache, batch)
-        next_tokens = self._read(jnp.argmax(logits[:, -1, :], axis=-1))
-        self.positions = self.positions + self.live
-        return next_tokens
+            (self.tokens, self.positions), self.cache = self._decode(
+                self.params, self.cache, batch)
 
-    def _at_max_len(self, slot: int, req: Request) -> bool:
-        return int(self._read(self.positions[slot])) >= self.max_len - 1
+    def _collect(self) -> np.ndarray:
+        return self._read(self.tokens)[:, 0]
 
     def _free(self, slot: int) -> None:
         self.positions = self.positions.at[slot].set(0)
         self.live = self.live.at[slot].set(0)
-
-    def _feed(self, tokens: np.ndarray) -> None:
-        self.tokens = self._put(tokens.astype(np.int32)[:, None])
 
 
 class StubEngine(SlotScheduler):
@@ -281,8 +291,7 @@ class StubEngine(SlotScheduler):
     Deterministic: a request's output is a pure function of its prompt
     (`stub_output`), so a routed K-replica execution must be
     token-identical to one local engine -- the completion-equivalence
-    property in tests/test_serve_plane.py. A request ends at `max_len`
-    where ServeEngine's would: once prompt and output fill it."""
+    property in tests/test_serve_plane.py."""
 
     def __init__(self, batch_slots: int, max_len: int = 1 << 30):
         super().__init__(batch_slots, max_len)
@@ -303,10 +312,10 @@ class StubEngine(SlotScheduler):
     def _prefill(self, req: Request, slot: int) -> int:
         return self.stub_output(req.prompt, len(req.output) + 1)[-1]
 
-    def _step(self) -> List[Optional[int]]:
-        return [None if r is None
-                else self.stub_output(r.prompt, len(r.output) + 1)[-1]
-                for r in self.slot_req]
+    def _launch(self) -> None:
+        self._next = [None if r is None
+                      else self.stub_output(r.prompt, len(r.output) + 1)[-1]
+                      for r in self.slot_req]
 
-    def _at_max_len(self, slot: int, req: Request) -> bool:
-        return len(req.prompt) + len(req.output) >= self.max_len
+    def _collect(self) -> List[Optional[int]]:
+        return self._next

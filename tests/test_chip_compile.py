@@ -9,9 +9,11 @@ The topology is described inside a fixture, never while modules are
 imported: only one process may hold the TPU library, and a test file
 that loaded it at import would break every other xdist worker."""
 import dataclasses
+import functools
 import json
 import os
 import re
+import types
 from pathlib import Path
 
 import jax
@@ -27,6 +29,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_gmm import moe_gmm
 from repro.kernels.ssm_scan import ssd_scan
 from repro.models import build_model
+from repro.serve.engine import ServeEngine
 
 LLAMA = get_config("llama3-8b")
 MOE = get_config("phi3.5-moe-42b-a6.6b")
@@ -107,8 +110,9 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 
 def _compile_decode_step(one_chip, cfg, slots, max_len):
-    """The engine's decode program (cache donated), its kernels compiled
-    for the chip, not interpreted."""
+    """The engine's decode program (`ServeEngine._decode_step`: the
+    model's step, its argmax and the advanced positions; cache donated),
+    its kernels compiled for the chip, not interpreted."""
     model = build_model(cfg)
     on_chip = lambda tree: jax.tree.map(
         lambda s: _sds(one_chip, s.shape, s.dtype), tree)
@@ -116,8 +120,11 @@ def _compile_decode_step(one_chip, cfg, slots, max_len):
                                     jax.random.PRNGKey(0)))
     cache = on_chip(jax.eval_shape(lambda: model.init_cache(slots, max_len)))
     batch = {"tokens": _sds(one_chip, (slots, 1), jnp.int32),
-             "positions": _sds(one_chip, (slots,), jnp.int32)}
-    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+             "positions": _sds(one_chip, (slots,), jnp.int32),
+             "live": _sds(one_chip, (slots,), jnp.int32)}
+    step = functools.partial(ServeEngine._decode_step,
+                             types.SimpleNamespace(model=model))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, cache, batch).compile()
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(cache))

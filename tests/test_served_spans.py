@@ -5,6 +5,7 @@ import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
@@ -69,11 +70,11 @@ def _engine(smoke_model, slots=2):
 
 def test_engine_counts_every_host_read_in_its_tick(smoke_model):
     engine = _engine(smoke_model)
-    reads = []
+    reads = []                  # per read: was it of the step's tokens
     read = engine._read
 
     def counted(x):
-        reads.append(1)
+        reads.append(x is engine.tokens)
         return read(x)
 
     engine._read = counted
@@ -91,9 +92,11 @@ def test_engine_counts_every_host_read_in_its_tick(smoke_model):
         admits = [s for s in spans
                   if s.name == "engine.admit" and s.parent == tick.id]
         assert all(a.attrs["syncs"] == 1 for a in admits)
-        # one argmax read, one per admission, at most one per slot
-        lo = 1 + len(admits)
-        assert lo <= tick.attrs["syncs"] <= lo + tick.attrs["active"]
+        # the in-flight step's tokens, and one per admission: no other
+        # read, of a slot's position or anything else
+        assert tick.attrs["syncs"] == tick.attrs["ahead"] + len(admits)
+    # every read outside an admission is of the launched step's tokens
+    assert reads.count(True) == sum(t.attrs["ahead"] for t in ticks)
     for name in ("engine.queue", "engine.admit"):
         ids = sorted(s.attrs["req"] for s in spans if s.name == name)
         assert ids == [r.id for r in reqs]
@@ -102,6 +105,61 @@ def test_engine_counts_every_host_read_in_its_tick(smoke_model):
         if s.name == "engine.queue":
             assert s.end <= admit[s.attrs["req"]].start
     assert {a.attrs["prompt"] for a in admit.values()} == {1, 2, 3, 4}
+
+
+def test_tick_returns_with_the_next_step_in_flight(smoke_model):
+    engine = _engine(smoke_model)
+    events = []                 # ("read" | "launch", the array it touched)
+    read, decode = engine._read, engine._decode
+
+    def read_tap(x):
+        events.append(("read", x))
+        return read(x)
+
+    def decode_tap(params, cache, batch):
+        out = decode(params, cache, batch)
+        events.append(("launch", out[0][0]))
+        return out
+
+    engine._read, engine._decode = read_tap, decode_tap
+    engine.add_request(Request(id=301, prompt=[1, 2, 3], max_new_tokens=4))
+    t0 = time.perf_counter()
+    assert engine.tick() == 1           # admits 301, launches its step
+    assert [e for e, _ in events] == ["read", "launch"]
+    assert engine._stepping == [0]
+    assert engine.slot_req[0].output and len(engine.slot_req[0].output) == 1
+    launched = events[-1][1]
+    events.clear()
+    assert engine.tick() == 1           # reads that step, launches the next
+    assert [e for e, _ in events] == ["read", "launch"]
+    assert events[0][1] is launched
+    assert len(engine.slot_req[0].output) == 2
+    engine.run_until_drained()
+    spans = _since(t0)
+    ticks = [s for s in spans if s.name == "engine.tick"]
+    # the admitting tick read no step; every later one read its
+    # predecessor's, the last launching none
+    assert [t.attrs["ahead"] for t in ticks] == [0, 1, 1, 1]
+    assert [t.attrs["active"] for t in ticks] == [1, 1, 1, 1]
+    assert engine._stepping == []
+    waits = [s for s in spans if s.name == "engine.step_wait"]
+    assert [w.parent for w in waits] == [t.id for t in ticks[1:]]
+
+
+def test_a_slot_freed_in_a_tick_is_refilled_in_it(smoke_model):
+    engine = _engine(smoke_model, slots=1)
+    first = Request(id=311, prompt=[1, 2], max_new_tokens=2)
+    second = Request(id=312, prompt=[3, 4], max_new_tokens=2)
+    engine.add_request(first)
+    engine.add_request(second)
+    engine.tick()                       # admits 311, 312 waits
+    assert engine.slot_req == [first] and engine.queue_len == 1
+    engine.tick()                       # 311 ends; 312 takes its slot
+    assert engine.pop_completed() == [first] and len(first.output) == 2
+    assert engine.slot_req == [second] and len(second.output) == 1
+    assert engine._stepping == [0]
+    engine.run_until_drained()
+    assert len(second.output) == 2
 
 
 def _held(t0, ids):
@@ -127,6 +185,20 @@ def test_admission_counts_the_slots_it_holds(smoke_model):
     engine.tick()
     assert _held(t0, {203, 204}) == [0, 1]
     engine.run_until_drained()
+    # a slot freed in a tick is refilled in that tick; the request that
+    # freed it is no longer held
+    engine = _engine(smoke_model)
+    t0 = time.perf_counter()
+    for i, budget in ((205, 6), (206, 2), (207, 2)):
+        engine.add_request(Request(id=i, prompt=[1, 2], max_new_tokens=budget))
+    engine.tick()                       # admits 205 and 206; 207 waits
+    assert _held(t0, {205, 206, 207}) == [0, 1]
+    assert engine.pop_completed() == [] and engine.queue_len == 1
+    engine.tick()                       # 206 ends, 207 takes its slot
+    assert [r.id for r in engine.pop_completed()] == [206]
+    assert [r.id for r in engine.slot_req] == [205, 207]
+    assert _held(t0, {207}) == [1]
+    engine.run_until_drained()
 
 
 def test_compile_inside_a_tick_is_recorded_under_it(smoke_model):
@@ -151,7 +223,8 @@ def test_compile_inside_a_tick_is_recorded_under_it(smoke_model):
 
 def test_decode_step_program_is_named_after_it(smoke_model):
     engine = _engine(smoke_model)
-    batch = {"tokens": engine.tokens, "positions": engine.positions}
+    batch = {"tokens": engine.tokens, "positions": engine.positions,
+             "live": engine.live}
     text = engine._decode.lower(engine.params, engine.cache, batch).as_text()
     assert text.startswith("module @jit__decode_step ")
 
@@ -218,3 +291,53 @@ def test_stub_and_served_engines_schedule_alike(smoke_model, trace, slots):
         == [a["req"] for name, _, a in spans if name == "engine.queue"]
     assert [a["active"] for name, _, a in spans if name == "engine.tick"] \
         == [row[0] for row in rows]
+    # a slot freed in a tick is refilled in it: no slot is left empty
+    # while a request waits
+    assert all(queued == 0 or None not in ids
+               for _, ids, _, queued, _, _ in rows)
+    if slots == 1:
+        # the requests answer one after another, each holding the slot for
+        # one tick per token after its first: one tick more than those
+        assert len(rows) == 1 + sum(n - 1 for n in lengths.values())
+
+
+def _straight(model, params, prompt, n, max_len):
+    """`n` greedy tokens of `prompt` alone: prefill, then one decode step
+    at a time on a one-row cache of `max_len`."""
+    logits, small = jax.jit(model.prefill)(
+        params, {"tokens": jnp.asarray([prompt], jnp.int32)})
+    cache = jax.tree.map(
+        lambda big, s: jnp.pad(s, [(0, b - a) for b, a in
+                                   zip(big.shape, s.shape)]).astype(big.dtype),
+        model.init_cache(1, max_len), small)
+    step = jax.jit(model.decode_step)
+    out = [int(jnp.argmax(logits[0, -1]))]
+    while len(out) < n:
+        batch = {"tokens": jnp.asarray([[out[-1]]], jnp.int32),
+                 "positions": jnp.asarray([len(prompt) + len(out) - 1],
+                                          jnp.int32)}
+        logits, cache = step(params, cache, batch)
+        out.append(int(jnp.argmax(logits[0, -1])))
+    return out
+
+
+@pytest.mark.parametrize("slots", [2, 3])
+@pytest.mark.parametrize("trace", sorted(TRACES))
+def test_served_outputs_are_straight_decodes(smoke_model, trace, slots):
+    model, params = smoke_model
+    engine = ServeEngine(model, params, batch_slots=slots, max_len=32)
+    reqs = {i: Request(id=i, prompt=[1 + (i + j) % 7 for j in range(n)],
+                       max_new_tokens=budget)
+            for i, n, budget, _ in TRACES[trace]}
+    tick = 0
+    while tick <= max(at for *_, at in TRACES[trace]) or engine.queue_len \
+            or any(r is not None for r in engine.slot_req):
+        for i, _, _, at in TRACES[trace]:
+            if at == tick:
+                engine.add_request(reqs[i])
+        engine.tick()
+        tick += 1
+    assert all(r.done for r in reqs.values())
+    for r in reqs.values():
+        assert r.output == _straight(model, params, r.prompt, len(r.output),
+                                     32), r.id
