@@ -2,7 +2,8 @@
 
 After the window, a sample of the finished requests, drawn from the seed
 and always holding the one with the most served tokens and the one with
-the longest prompt, is run through the plain float32 reference once each:
+the longest prompt, is run through the plain float32 reference of the
+configuration's family (`families/<family>.py:logits_at`) once each:
 the prompt followed by the served tokens. At every position where a token
 was served (the first from prefill, the rest from decode steps at every
 depth into the cache) the gap is how far the served token's reference
@@ -12,7 +13,7 @@ serving of the bfloat16 model puts a near-tie first now and then, which
 reads a small gap; a wrong cache entry, position or token reads several
 standard deviations.
 
-The control puts the reference at fp8 (`reference.logits_at(quant=)`) in
+The control puts the reference at fp8 (`logits_at(quant="fp8")`) in
 the program's place and reads, at the same positions, the gap of the
 token that it puts first.
 """
@@ -23,8 +24,6 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from perfbench import reference
 
 MIN_BUCKET = 256
 
@@ -68,12 +67,12 @@ def _gaps(ref, picked):
     return (best - got) / jnp.std(ref, axis=-1)
 
 
-def served_gaps(params, cfg: Dict[str, Any], reqs: Sequence[Any],
+def served_gaps(family, params, cfg: Dict[str, Any], reqs: Sequence[Any],
                 max_len: int, out_max: int,
                 control: Optional[str] = None) -> Dict[str, List[float]]:
     """Per served token, the gap of the served token ("program") and,
-    with `control`, of the control's first choice ("control")."""
-    dims = reference.Dims.of(cfg)
+    with `control`, of the control's first choice ("control"), both read
+    under `family.logits_at`."""
     out: Dict[str, List[float]] = {"program": [], "control": []}
     for r in reqs:
         n, m = len(r.prompt), len(r.output)
@@ -84,14 +83,13 @@ def served_gaps(params, cfg: Dict[str, Any], reqs: Sequence[Any],
         at[:m] = np.arange(n - 1, n - 1 + m)
         served = np.zeros((out_max,), np.int32)
         served[:m] = r.output
-        ref = reference.logits_at(params, jnp.asarray(tokens),
-                                  jnp.asarray(at), dims=dims)
+        ref = family.logits_at(params, jnp.asarray(tokens),
+                               jnp.asarray(at), cfg=cfg)
         out["program"] += np.asarray(_gaps(ref, jnp.asarray(served)))[:m] \
             .tolist()
         if control:
-            low = reference.logits_at(params, jnp.asarray(tokens),
-                                      jnp.asarray(at), dims=dims,
-                                      quant=control)
+            low = family.logits_at(params, jnp.asarray(tokens),
+                                   jnp.asarray(at), cfg=cfg, quant=control)
             first = jnp.argmax(low, axis=-1).astype(jnp.int32)
             out["control"] += np.asarray(_gaps(ref, first))[:m].tolist()
         del ref

@@ -7,8 +7,8 @@ chip at a cell's own size.
 
 For each seed, one run of the cell (a short window at the cell's own load)
 whose sample of served requests is also put through the reference at fp8
-(`reference.logits_at(quant=)`): the gap of the token that fp8 puts first,
-read under the float32 reference at the same positions. Prints one JSON line per seed
+(the family's `logits_at(quant="fp8")`): the gap of the token that fp8
+puts first, read under the float32 reference at the same positions. Prints one JSON line per seed
 with the program's numbers and verdict and the control's, the control's
 verdict taken by the same comparison (`check.verdict`) against the limits
 in `limits/<cell>.json`, which are set from these readings. Exits 1 where

@@ -10,14 +10,15 @@ process (which holds the chip or chips):
 
 Everything a cell needs is found by name: `BENCHMARK.json` names the cell's
 configuration (`configs/<config>.json`) and traffic mix
-(`traffic/<mix>.json`); the limits of its comparison are in
+(`traffic/<mix>.json`); the configuration's `family` names the module that
+gives its program config, weights, reference and cost counts
+(`families/<family>.py`); the limits of its comparison are in
 `limits/<cell>.json`; every metric is read by `metrics/<metric>.py`. A
 cell on n chips serves n one-chip replicas behind the router.
 """
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import gc
 import importlib.util
 import json
@@ -26,6 +27,7 @@ import tempfile
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 BENCH = Path(__file__).resolve().parent
@@ -53,6 +55,7 @@ class Cell:
     limits: Dict[str, float]
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    family: ModuleType
 
 
 def _reports(metric: Dict[str, Any], cell: str) -> bool:
@@ -71,16 +74,38 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     limits = json.loads((BENCH / "limits" / f"{name}.json").read_text())
     return Cell(name, cfg, mix, int(w["chips"]), limits["limits"],
                 [m for m in spec["end_to_end"] if _reports(m, name)],
-                [m for m in spec["per_layer"] if _reports(m, name)])
+                [m for m in spec["per_layer"] if _reports(m, name)],
+                family(cfg["family"]))
+
+
+def _module(path: Path, name: str) -> ModuleType:
+    """The module in the file `path`, loaded once per process."""
+    if not path.is_file():
+        raise FileNotFoundError(f"no file {path}")
+    mod = sys.modules.get(name)
+    if mod is None or Path(mod.__file__) != path:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(metric: str) -> Callable[[Any], Optional[float]]:
     path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"perfbench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path, f"perfbench_metric_{metric.replace('.', '_')}").read
+
+
+def family(name: str, root: Path = BENCH) -> ModuleType:
+    """The benchmark family `<root>/families/<name>.py`, which gives a
+    configuration of that family its program config (`model_config(cfg)`),
+    seeded weights in the served layout (`make_params(key, cfg)`), plain
+    float32 reference and fp8 control (`logits_at(params, tokens, at, *,
+    cfg, quant=None)`) and the counts the `model.*` readers divide
+    (`decode_step_bytes(cfg, contexts)`, `decode_flops(cfg, contexts)`,
+    `prefill_flops(cfg, n)`)."""
+    return _module(root / "families" / f"{name}.py",
+                   f"perfbench_family_{name}")
 
 
 @dataclass
@@ -137,12 +162,6 @@ def enable_cache() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
-def model_config(cfg: Dict[str, Any]):
-    from repro.configs.base import ModelConfig
-    names = {f.name for f in dataclasses.fields(ModelConfig)}
-    return ModelConfig(**{k: v for k, v in cfg.items() if k in names})
-
-
 def devices_for(chips: int, require_tpu: bool) -> List[Any]:
     devs = jax.devices()
     if require_tpu and devs[0].platform != "tpu":
@@ -154,9 +173,10 @@ def devices_for(chips: int, require_tpu: bool) -> List[Any]:
 
 def warm(engine, lens) -> None:
     """Compile and run every program serving will use (prefill at each of
-    the mix's prompt lengths, the cache scatter, the decode step and the
-    reads of a slot's position) by serving one request per length locally,
-    each long enough to take the path of a request that goes on decoding."""
+    the mix's prompt lengths, the cache scatter and the decode step, which
+    takes its argmax and the slots' next positions inside it) by serving
+    one request per length locally, each long enough to take the path of a
+    request that goes on decoding."""
     from repro.serve.engine import Request
     for i, n in enumerate(lens):
         engine.add_request(Request(id=-1 - i, prompt=[1] * n,
@@ -266,9 +286,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     mix, cfg = cell.mix, cell.cfg
     slots, max_len = mix["engine"]["slots"], mix["engine"]["max_len"]
     reqs = traffic.generate(mix, seconds, seed, cfg["vocab_size"])
-    model = build_model(model_config(cfg))
+    model = build_model(cell.family.model_config(cfg))
     t = time.perf_counter()
-    params = [weights.init_on_device(cfg, seed, d) for d in devices]
+    params = [weights.init_on_device(cell.family, cfg, seed, d)
+              for d in devices]
     jax.block_until_ready(params)
     log(f"weights {sum(a.nbytes for a in jax.tree.leaves(params[0])) / 1e9:.3f}"
         f" GB per chip in {time.perf_counter() - t:.2f} s")
@@ -341,7 +362,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     short = sum(len(r.output) != r.max_new_tokens for r in finished)
     sample = check.choose_sample(finished, seed, mix["check"])
     t = time.perf_counter()
-    gaps = check.served_gaps(params[0], cfg, sample, max_len,
+    gaps = check.served_gaps(cell.family, params[0], cfg, sample, max_len,
                              mix["output_len"]["max"], control)
     log(f"reference over {len(sample)} requests, "
         f"{len(gaps['program'])} served tokens, in "

@@ -1,9 +1,9 @@
 """Share of the HBM roofline reached by the decode step: the bytes a step
 needs (every layer's weights, the output head, and the KV entries of the
-live contexts only; `costs.decode_step_bytes`) over the chip's HBM
+live contexts only; the family's `decode_step_bytes`) over the chip's HBM
 bandwidth, over the step's device time. A step that reads the whole
 max_len buffer, or idles inside, reads lower."""
-from perfbench import costs, programs
+from perfbench import programs
 
 
 def read(r):
@@ -13,6 +13,6 @@ def read(r):
     steps = r.probes.within(r.probes.decodes, r.lo, r.hi)
     if not n or not steps:
         return None
-    need = sum(costs.decode_step_bytes(r.cell.cfg, c) for _, c in steps) \
-        / len(steps)
+    count = r.cell.family.decode_step_bytes
+    need = sum(count(r.cell.cfg, c) for _, c in steps) / len(steps)
     return 100.0 * need / r.peaks()["hbm_bytes_per_s"] / (secs / n)

@@ -19,6 +19,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import copy  # noqa: E402
+import dataclasses  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -42,7 +43,7 @@ def main(argv) -> int:
     base = harness.load_cell(args.workload)
     log = lambda msg: print(msg, file=sys.stderr, flush=True)  # noqa: E731
     for rate in [float(r) for r in args.rates.split(",")]:
-        cell = copy.deepcopy(base)
+        cell = dataclasses.replace(base, mix=copy.deepcopy(base.mix))
         cell.mix["arrivals"]["rate_per_s"] = rate
         try:
             res, _, win = harness.run_cell(

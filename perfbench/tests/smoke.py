@@ -20,7 +20,7 @@ def cell(name: str = "granite-8b.chat", layers: int = 2,
     mix["engine"] = {"slots": 4, "max_len": 64}
     mix["drain_cap_s"] = 20
     return harness.Cell(real.name, cfg, mix, 1, real.limits, real.end_to_end,
-                        real.per_layer)
+                        real.per_layer, real.family)
 
 
 def run(c: harness.Cell, seed: int = 2**31 + 7, seconds: float = 3.0,

@@ -31,7 +31,7 @@ def mha_cell() -> harness.Cell:
     mix["engine"]["slots"] = 2
     mix["check"].update(min_requests=3, max_requests=3, min_tokens=300)
     return harness.Cell(real.name, cfg, mix, 1, real.limits, real.end_to_end,
-                        real.per_layer)
+                        real.per_layer, real.family)
 
 
 def run(seed: int):

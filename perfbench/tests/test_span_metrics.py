@@ -48,7 +48,7 @@ def engine():
     from repro.models import build_model
     from repro.serve.engine import ServeEngine
     c = smoke.cell()
-    model = build_model(harness.model_config(c.cfg))
+    model = build_model(c.family.model_config(c.cfg))
     params = jax.jit(model.init_params)(jax.random.PRNGKey(0))
     return ServeEngine(model, params, batch_slots=3, max_len=64)
 

@@ -7,7 +7,7 @@ import re
 import jax
 import pytest
 
-from perfbench import harness, weights
+from perfbench import harness
 from perfbench.harness import BENCH, ROOT
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -48,37 +48,47 @@ def test_cell_loads_by_name(cell):
     assert c.chips in (1, 4)
     assert {"gap_max", "gap_mean", "short_outputs"} <= set(c.limits)
     assert c.per_layer and any(m["name"] == "setup_s" for m in c.end_to_end)
-    harness.model_config(c.cfg)
+    c.family.model_config(c.cfg)
+
+
+def served_layout(fam, cfg):
+    """The family's weights and the served model's, as trees of shapes."""
+    from repro.models import build_model
+    ours = jax.eval_shape(lambda k: fam.make_params(k, cfg),
+                          jax.random.key(0, impl="rbg"))
+    served = jax.eval_shape(build_model(fam.model_config(cfg)).init_params,
+                            jax.random.PRNGKey(0))
+    shape = lambda t: jax.tree.map(lambda a: (a.shape, a.dtype), t)
+    return shape(ours), shape(served)
 
 
 @pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda e: e["name"])
 def test_config_files(entry):
+    """Every configuration names its family, and the family's weights
+    have the served layout, whatever the family."""
     cfg = json.loads((ROOT / entry["file"]).read_text())
     assert cfg["name"] == entry["name"]
     assert sorted(cfg["reduced"]) == sorted(entry["reduced"])
     assert set(cfg["published"]) >= set(entry["reduced"])
-    assert cfg["head_dim"] * cfg["n_heads"] == cfg["d_model"]
-
-
-def test_granite_is_the_registry_model_cut_to_one_stage():
-    from repro.configs import get_config
-    cfg = harness.load_cell("granite-8b.chat").cfg
-    want = get_config("granite-8b").replace(n_layers=18, kv_replication=1,
-                                            head_dim=128)
-    assert harness.model_config(cfg) == want
-    assert get_config("granite-8b").n_layers == cfg["published"]["n_layers"]
+    ours, served = served_layout(harness.family(cfg["family"]), cfg)
+    assert ours == served
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_weights_have_the_served_layout(cell):
-    from repro.models import build_model
-    cfg = harness.load_cell(cell).cfg
-    ours = jax.eval_shape(lambda k: weights.make_params(k, cfg),
-                          jax.random.key(0, impl="rbg"))
-    served = jax.eval_shape(build_model(harness.model_config(cfg)).init_params,
-                            jax.random.PRNGKey(0))
-    shape = lambda t: jax.tree.map(lambda a: (a.shape, a.dtype), t)
-    assert shape(ours) == shape(served)
+    """The family a cell loads with draws the tree its program serves."""
+    c = harness.load_cell(cell)
+    ours, served = served_layout(c.family, c.cfg)
+    assert ours == served
+
+
+def test_granite_is_the_registry_model_cut_to_one_stage():
+    from repro.configs import get_config
+    c = harness.load_cell("granite-8b.chat")
+    want = get_config("granite-8b").replace(n_layers=18, kv_replication=1,
+                                            head_dim=128)
+    assert c.family.model_config(c.cfg) == want
+    assert get_config("granite-8b").n_layers == c.cfg["published"]["n_layers"]
 
 
 def test_peaks_table_is_keyed_by_device_kind():
